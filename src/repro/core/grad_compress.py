@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.core.convert import MXArray, mx_dequantize, mx_quantize
 from repro.core.spec import QuantSpec, resolve_spec
-from repro.dist import compat
 
 AxisNames = Sequence[str]
 
@@ -56,7 +55,7 @@ def mx_allreduce_mean(g: jax.Array, axis_names: AxisNames, spec=None,
     names = tuple(axis_names)
     n = 1
     for a in names:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     if n == 1:
         return g
     shape = g.shape
@@ -68,7 +67,7 @@ def mx_allreduce_mean(g: jax.Array, axis_names: AxisNames, spec=None,
     # each step leaves this device with a 1/k shard of the partial sums
     x = flat
     for a in names:
-        k = compat.axis_size(a)
+        k = jax.lax.axis_size(a)
         x = jax.lax.psum_scatter(x.reshape(k, -1), a,
                                  scatter_dimension=0, tiled=False)
     shard = x.reshape(-1) / n

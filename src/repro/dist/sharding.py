@@ -36,8 +36,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
-
 Rules = Dict[str, Any]
 
 DP_AXES = ("pod", "data")       # data-parallel axes, outermost first
@@ -160,6 +158,13 @@ def _axes_size(mesh, axes: Tuple[str, ...]) -> int:
     return n
 
 
+def current_mesh():
+    """The mesh installed by ``jax.set_mesh`` (an AbstractMesh: axis names
+    and sizes), or None when no mesh is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
 def model_axis_size() -> int:
     """Mesh extent behind the logical "model" name under the installed
     rules (1 when no rules/mesh are active).  Divisibility checks at MX
@@ -168,7 +173,7 @@ def model_axis_size() -> int:
     rules = current_rules()
     if rules is None:
         return 1
-    mesh = compat.current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return 1
     axes = tuple(a for a in (rules.get("model") or ())
@@ -186,7 +191,7 @@ def logical(x: jax.Array, *names) -> jax.Array:
     rules = current_rules()
     if rules is None:
         return x
-    mesh = compat.current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return x
     entries = []
@@ -201,7 +206,7 @@ def logical(x: jax.Array, *names) -> jax.Array:
             # None is a *hard* replication constraint — this is what forces
             # the per-layer FSDP all-gather at weight use points
             entries.append(None)
-    return compat.constrain(x, P(*entries), mesh)
+    return jax.lax.with_sharding_constraint(x, P(*entries))
 
 
 # =============================================================================

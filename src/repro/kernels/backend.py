@@ -1,22 +1,20 @@
 """Backend-aware execution-mode defaults for the Pallas kernels.
 
 Every kernel entry point takes ``interpret=None`` and resolves it through
-``resolve_interpret``: explicit booleans win, then the
-``REPRO_PALLAS_INTERPRET`` environment override, and finally the backend —
-interpret mode (kernel bodies executed in Python, the correctness path)
-everywhere except a real TPU, where the kernels compile to Mosaic.  This
-keeps CPU CI bit-exact while real hardware gets compiled kernels without
-any call-site churn.
+``resolve_interpret``: an explicit boolean wins, otherwise the kernels run
+in interpret mode (kernel bodies executed as plain JAX, the correctness
+path) exactly when the default backend is the CPU, and compile to Mosaic
+on the TPU.
 
-**Supervised dispatch** (graceful degradation): callers with a dense
-fallback route their kernel through :func:`supervised` — on the first
-failure of a named op (a Pallas trace/lowering error, or an injected
-launch fault) the op is marked degraded, the failure is logged once, and
-``supervised`` returns None so the caller's existing ``if out is None``
-dense path takes over.  Every later trace of that op skips the kernel
-outright, so serving keeps running at dense speed instead of crashing.
+**Supervised dispatch** (fault drills): callers with a dense fallback
+route their kernel through :func:`supervised`.  An injected launch fault
+(:func:`inject_failure`, driven by ``repro.serve.faults``) marks the op
+degraded, logs once, and makes ``supervised`` return None, so the caller's
+``if out is None`` dense path takes over for the rest of the process.
 Both paths are token-identical by construction (asserted by the kernel
-correctness suites), so degradation changes throughput, never tokens.
+correctness suites).  A real kernel error is never caught here: it
+propagates, so a kernel the chip's compiler refuses fails the run instead
+of quietly serving at dense speed.
 """
 from __future__ import annotations
 
@@ -26,21 +24,14 @@ from typing import Dict, Optional
 
 import jax
 
-ENV_VAR = "REPRO_PALLAS_INTERPRET"
-
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Resolve an ``interpret`` kwarg: explicit value > env var > backend.
-
-    ``None`` means "interpret only off-TPU".  The result is a plain bool so
-    it can ride through jit static arguments.
-    """
+    """Resolve an ``interpret`` kwarg: explicit value, else interpret mode
+    exactly when the default backend is the CPU.  The result is a plain
+    bool so it can ride through jit static arguments."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get(ENV_VAR)
-    if env is not None:
-        return env != "0"
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 MATMUL_ENV_VAR = "REPRO_MX_MATMUL_IMPL"
@@ -52,10 +43,9 @@ def resolve_matmul_impl(impl: Optional[str] = None) -> str:
 
     ``"fused"`` runs the Pallas dequant-in-VMEM kernel (sub-byte codes
     unpacked inside the tile loop; fp weights never hit HBM); ``"einsum"``
-    dequantizes the whole weight and falls back to a plain einsum.  Like
-    ``resolve_interpret``, the ``REPRO_MX_MATMUL_IMPL`` environment override
-    is read at trace time, so flipping it only affects newly traced
-    computations.
+    dequantizes the whole weight and falls back to a plain einsum.  The
+    ``REPRO_MX_MATMUL_IMPL`` environment override is read at trace time,
+    so flipping it only affects newly traced computations.
     """
     if impl is None:
         impl = os.environ.get(MATMUL_ENV_VAR, "fused")
@@ -66,7 +56,7 @@ def resolve_matmul_impl(impl: Optional[str] = None) -> str:
 
 
 # =============================================================================
-# Supervised kernel dispatch (log once, degrade to dense, keep serving)
+# Supervised kernel dispatch (an injected fault degrades to dense)
 # =============================================================================
 class KernelFault(RuntimeError):
     """An injected kernel launch failure (see ``repro.serve.faults``)."""
@@ -101,25 +91,21 @@ def reset_degradation() -> None:
 
 
 def inject_failure(op: str) -> None:
-    """Arm a one-shot failure: the next ``supervised(op, ...)`` raises
-    (and therefore degrades) instead of running the kernel.  Consumed at
+    """Arm a one-shot failure: the next ``supervised(op, ...)`` degrades
+    the op instead of running the kernel.  Consumed at
     trace time — the caller must force a retrace (fresh ``jax.jit``
     wrapper) for an already-compiled computation to hit it."""
     _injected.add(op)
 
 
 def supervised(op: str, fn, *args, **kwargs):
-    """Run kernel ``fn`` under supervision.  Returns its result, or None
-    when ``op`` is degraded or ``fn`` raises — the caller's dense
-    fallback path must handle None (the pre-existing contract of the
-    paged-attention kernel gate)."""
+    """Run kernel ``fn`` unless ``op`` is degraded or an injected fault is
+    armed for it; those return None and the caller's dense fallback path
+    serves.  Exceptions raised by ``fn`` itself propagate."""
     if op in _degraded:
         return None
-    try:
-        if op in _injected:
-            _injected.discard(op)
-            raise KernelFault(f"injected {op} launch failure")
-        return fn(*args, **kwargs)
-    except Exception as e:          # noqa: BLE001 — demote, don't crash
-        degrade(op, repr(e))
+    if op in _injected:
+        _injected.discard(op)
+        degrade(op, repr(KernelFault(f"injected {op} launch failure")))
         return None
+    return fn(*args, **kwargs)

@@ -9,24 +9,27 @@ would write the f32 cache back to HBM and give most of it back).
 K and V carry independent ``QuantSpec``s (the ``kv_key`` / ``kv_value``
 policy roles), so e.g. INT8 keys can pair with E2M1 values in one cache.
 
-Grid (B, Hq, nk); per step:
-    q_ref        (1, 1, D)        query for this (batch, head)
-    kc/vc_ref    (1, blk_k, 1, D)       u8 element codes (kv head = h//rep)
-    ks/vs_ref    (1, blk_k, 1, D/32)    u8 E8M0 scales
-    mask_ref     (1, blk_k)       valid-position mask (pos-dependent)
-    scratch      acc (1, D) f32, m/l (1,) f32
-
-``mx_paged_decode_attention`` is the continuous-batching variant: K/V live
-in a shared page pool (pages of ``page_size`` tokens, sub-byte codes
-bit-packed via repro.core.pack when the spec says ``packed``) and each
-slot's logical sequence is the concatenation of the pages named by its
+``mx_paged_decode_attention`` is the kernel: K/V live in a shared page
+pool (pages of ``page_size`` tokens, sub-byte codes bit-packed via
+repro.core.pack when the spec says ``packed``) and each slot's logical
+sequence is the concatenation of the pages named by its
 block-table row.  The block table and per-slot lengths ride in as
 scalar-prefetch operands so the BlockSpec index maps can translate
 (slot, page-step) -> physical page before the DMA is issued — the gather
 happens at the HBM->VMEM boundary and HBM traffic stays at the quantized
-cache, exactly as in the contiguous kernel.  The two pools are sized
-per-role: a packed E2M1 value pool really is half the bytes of its INT8
-key pool.
+cache.  The two pools are sized per-role: a packed E2M1 value pool really
+is half the bytes of its INT8 key pool.  ``mx_decode_attention`` serves a
+contiguous (B, S, Hkv, ·) cache through the same kernel, as a pool with an
+identity block table.
+
+Grid (B, Hq, max_pages); per step:
+    q_ref        (1, 1, 1, D)         query for this (slot, head)
+    kc/vc_ref    (1, page, Hkv, CB)   u8 codes of one page, every kv head
+                                      (the TPU refuses a size-1 block on
+                                      the second-minor axis); the kernel
+                                      selects head h // rep
+    ks/vs_ref    (1, page, Hkv, D/32) u8 E8M0 scales
+    scratch      acc (1, D) f32, m/l (1,) f32
 """
 from __future__ import annotations
 
@@ -40,10 +43,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.convert import decode_elements, scale_to_f32
-from repro.core.pack import unpack_codes
+from repro.core.pack import pack_codes
 from repro.core.spec import QuantSpec, resolve_kv_specs
 from repro.kernels import accounting
 from repro.kernels.backend import resolve_interpret
+from repro.kernels.lanes import gather_lanes, unpack_codes_lanes
 
 DEFAULT_BLK_K = 512
 NEG_INF = -1e30
@@ -64,58 +68,27 @@ def _require_block32(key_spec: QuantSpec, value_spec: QuantSpec,
                 f"layouts")
 
 
-def _dequant_block(codes, scales, spec: QuantSpec):
-    """(blk_k, D) u8 + (blk_k, D/32) u8 -> (blk_k, D) f32, in VMEM."""
-    blk, d = codes.shape
+def _dequant_block(codes, scales, spec: QuantSpec, d: int):
+    """(blk, CB) u8 codes + (blk, D/32) u8 scales -> (blk, D) f32, in VMEM.
+    Bit-packed sub-byte codes (CB < D) are unpacked first.  Codes and
+    scales are spread over the head dim by lane gathers, not reshapes,
+    which Mosaic refuses on the lane axis."""
+    if codes.shape[-1] != d:
+        codes = unpack_codes_lanes(codes, spec.fmt, d)
     elem = decode_elements(codes, spec.format, spec.mode)
-    sfac = scale_to_f32(scales)                     # (blk_k, D/32)
-    w = elem.reshape(blk, d // 32, 32) * sfac[:, :, None]
-    return w.reshape(blk, d)
+    sfac = scale_to_f32(gather_lanes(scales, d, lambda c: c // 32))
+    return elem * sfac
 
 
-def _dequant_pool_block(codes, scales, spec: QuantSpec, d):
-    """(blk, CB) pool u8 + (blk, D/32) u8 -> (blk, D) f32.  Unpacks the
-    bit-packed sub-byte codes in VMEM when the spec stores packed
-    (identity for 8-bit formats), then dequantizes like the contiguous
-    path."""
-    if spec.packed:
-        codes = unpack_codes(codes, spec.fmt, d)
-    return _dequant_block(codes, scales, spec)
-
-
-def _decode_kernel(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, mask_ref, o_ref,
-                   acc, mrow, lrow, *, key_spec: QuantSpec,
-                   value_spec: QuantSpec, nk: int):
-    jk = pl.program_id(2)
-
-    @pl.when(jk == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        mrow[...] = jnp.full_like(mrow, NEG_INF)
-        lrow[...] = jnp.zeros_like(lrow)
-
-    q = q_ref[0, 0].astype(jnp.float32)                    # (1, D)
-    k = _dequant_block(kc_ref[0, :, 0, :], ks_ref[0, :, 0, :], key_spec)
-    v = _dequant_block(vc_ref[0, :, 0, :], vs_ref[0, :, 0, :], value_spec)
-    d = q.shape[-1]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) \
-        / np.sqrt(d)                                       # (1, blk_k)
-    valid = mask_ref[0][None, :]
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = mrow[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    lrow[...] = lrow[...] * alpha + jnp.sum(p, axis=-1)
-    acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    mrow[...] = m_new
-
-    @pl.when(jk == nk - 1)
-    def _done():
-        denom = jnp.where(lrow[...] == 0.0, 1.0, lrow[...])
-        o_ref[0, 0] = (acc[...] / denom[:, None]).astype(o_ref.dtype)
+def _kv_head(ref, g):
+    """Rows of kv head ``g`` (traced) from a (1, blk, Hkv, X) block.  The
+    block spans every kv head because the TPU refuses a size-1 block on
+    the second-minor axis; a static select picks the head (Hkv is small)."""
+    blk = ref[0]
+    out = blk[:, 0, :]
+    for i in range(1, blk.shape[1]):
+        out = jnp.where(g == i, blk[:, i, :], out)
+    return out
 
 
 def mx_decode_attention(q: jax.Array, k_codes: jax.Array,
@@ -148,47 +121,27 @@ def _mx_decode_attention(q, k_codes, k_scales, v_codes, v_scales, pos,
                          key_spec: QuantSpec, value_spec: QuantSpec,
                          rep: int, blk_k: int,
                          interpret: bool) -> jax.Array:
-    b, _, hq, d = q.shape
-    s, hkv = k_codes.shape[1], k_codes.shape[2]
+    """The contiguous cache is a page pool whose block table is the
+    identity: slot b's blk_k-token chunks are pages b*nk .. b*nk+nk-1.
+    Sub-byte codes are packed as a pool stores them, so both layouts run
+    the one kernel on the same bytes."""
+    b, s, _, d = k_codes.shape
+    if k_codes.shape[-1] != key_spec.storage_nbytes(d):
+        k_codes = pack_codes(k_codes, key_spec.fmt)
+    if v_codes.shape[-1] != value_spec.storage_nbytes(d):
+        v_codes = pack_codes(v_codes, value_spec.fmt)
     bk = min(blk_k, s)
     assert s % bk == 0, (s, bk)
     nk = s // bk
-    mask = (jnp.arange(s)[None, :] <= pos).astype(jnp.bool_)
-    mask = jnp.broadcast_to(mask, (b, s))
-    qt = q[:, 0][:, :, None, :]                            # (B, Hq, 1, D)
-    kernel = functools.partial(_decode_kernel, key_spec=key_spec,
-                               value_spec=value_spec, nk=nk)
-    nbl = d // 32
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, hq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, d), lambda bb, h, j: (bb, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bb, h, j, rep=rep: (bb, j, h // rep, 0)),
-            pl.BlockSpec((1, bk, 1, nbl),
-                         lambda bb, h, j, rep=rep: (bb, j, h // rep, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bb, h, j, rep=rep: (bb, j, h // rep, 0)),
-            pl.BlockSpec((1, bk, 1, nbl),
-                         lambda bb, h, j, rep=rep: (bb, j, h // rep, 0)),
-            pl.BlockSpec((1, bk), lambda bb, h, j: (bb, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, d), lambda bb, h, j: (bb, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, 1, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, k_codes, k_scales, v_codes, v_scales, mask)
-    # analytic cost: dequant+dot over the full cache per query
-    flops = 4.0 * b * hq * s * d + 10.0 * b * hq * s * d  # dots + dequant
-    io = (k_codes.size + v_codes.size + k_scales.size + v_scales.size
-          + q.size * q.dtype.itemsize * 2)
-    accounting.record(flops, io)
-    return out.transpose(0, 2, 1, 3)                       # (B, 1, Hq, D)
+
+    def pages(x):
+        return x.reshape((b * nk, bk) + x.shape[2:])
+
+    block_tables = jnp.arange(b * nk, dtype=jnp.int32).reshape(b, nk)
+    lengths = jnp.full((b,), pos, jnp.int32)
+    return _mx_paged_decode_attention(
+        q, pages(k_codes), pages(k_scales), pages(v_codes), pages(v_scales),
+        block_tables, lengths, key_spec, value_spec, rep, interpret)
 
 
 # =============================================================================
@@ -196,7 +149,8 @@ def _mx_decode_attention(q, k_codes, k_scales, v_codes, v_scales, pos,
 # =============================================================================
 def _paged_kernel(bt_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
                   o_ref, acc, mrow, lrow, *, key_spec: QuantSpec,
-                  value_spec: QuantSpec, d: int, page: int, np_max: int):
+                  value_spec: QuantSpec, d: int, page: int, np_max: int,
+                  rep: int):
     bb = pl.program_id(0)
     jk = pl.program_id(2)
 
@@ -206,11 +160,11 @@ def _paged_kernel(bt_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
         mrow[...] = jnp.full_like(mrow, NEG_INF)
         lrow[...] = jnp.zeros_like(lrow)
 
+    g = pl.program_id(1) // rep
     q = q_ref[0, 0].astype(jnp.float32)                    # (1, D)
-    k = _dequant_pool_block(kc_ref[0, :, 0, :], ks_ref[0, :, 0, :],
-                            key_spec, d)
-    v = _dequant_pool_block(vc_ref[0, :, 0, :], vs_ref[0, :, 0, :],
-                            value_spec, d)
+    k = _dequant_block(_kv_head(kc_ref, g), _kv_head(ks_ref, g), key_spec, d)
+    v = _dequant_block(_kv_head(vc_ref, g), _kv_head(vs_ref, g), value_spec,
+                       d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) \
         / np.sqrt(d)                                       # (1, page)
@@ -283,10 +237,10 @@ def _mx_paged_decode_attention(q, kc_pool, ks_pool, vc_pool, vs_pool,
     qt = q[:, 0][:, :, None, :]                            # (B, Hq, 1, D)
     kernel = functools.partial(_paged_kernel, key_spec=key_spec,
                                value_spec=value_spec, d=d, page=page,
-                               np_max=np_max)
+                               np_max=np_max, rep=rep)
 
-    def page_map(bb, h, j, bt, ln, rep=rep):
-        return (bt[bb, j], 0, h // rep, 0)
+    def page_map(bb, h, j, bt, ln):
+        return (bt[bb, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -294,10 +248,10 @@ def _mx_paged_decode_attention(q, kc_pool, ks_pool, vc_pool, vs_pool,
         in_specs=[
             pl.BlockSpec((1, 1, 1, d),
                          lambda bb, h, j, bt, ln: (bb, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, cb_k), page_map),
-            pl.BlockSpec((1, page, 1, nbl), page_map),
-            pl.BlockSpec((1, page, 1, cb_v), page_map),
-            pl.BlockSpec((1, page, 1, nbl), page_map),
+            pl.BlockSpec((1, page, hkv, cb_k), page_map),
+            pl.BlockSpec((1, page, hkv, nbl), page_map),
+            pl.BlockSpec((1, page, hkv, cb_v), page_map),
+            pl.BlockSpec((1, page, hkv, nbl), page_map),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, d),
                                lambda bb, h, j, bt, ln: (bb, h, 0, 0)),

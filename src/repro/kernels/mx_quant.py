@@ -2,20 +2,22 @@
 
 TPU-native adaptation of the combinational circuit (DESIGN.md §2):
 
-  * the paper's 32-input comparator tree  -> lane-local max over a 32-wide
-    trailing sub-axis of the VMEM tile (the VPU reduces with the same
-    O(log 32) tree, 8x128 lanes at a time);
+  * the paper's 32-input comparator tree  -> a max over each block's 32
+    lanes of the VMEM tile, as a 5-level butterfly of lane rotations (the
+    same O(log 32) tree, 8x128 lanes at a time);
   * the 32 parallel LUT quantizers        -> branchless integer ops on the
     bitcast(u32) view of the tile (mask/shift exponent extract, add-shift
     ties-away rounding, selects for FTZ / saturation / markers);
   * the 1288-pin I/O interface            -> double-buffered HBM->VMEM tile
     pipeline driven by ``pl.pallas_call`` BlockSpecs.
 
-Tile geometry: inputs are processed as (BM, BN) f32 tiles with BN a multiple
-of 32*128 so each 8x128 VREG row holds 4 whole MX blocks; the per-block scale
-tile is (BM, BN//32).  Default (256, 512) => 512 KiB in + 132 KiB out per
-grid step, comfortably inside a v5e core's ~16 MiB VMEM with double
-buffering.
+Tile geometry: each grid step converts a (BM, BN) tile.  The shared scales
+leave as a (BM, BN//32) tile, and the TPU takes a block whose last axis is
+a multiple of 128 or the whole axis, so BN is the whole (padded) row or a
+multiple of 128*32.  Default (32, 4096) => 512 KiB of f32 in, 128 KiB of
+codes and 4 KiB of scales out per step.  The per-block max and the scale
+compaction move data across lanes with ``kernels.lanes`` (Mosaic refuses
+lane-splitting reshapes).
 
 The kernel body reuses the *same* integer-exact element functions as the
 pure-JAX reference (repro/core/convert.py), so tests assert bit-identity.
@@ -35,29 +37,35 @@ from repro.core.convert import (_f32_fields, _quant_float_ocp,
 from repro.core.formats import MXFormat
 from repro.core.spec import QuantSpec, resolve_spec
 from repro.kernels.backend import resolve_interpret
+from repro.kernels.lanes import gather_lanes, segment_max
 
-DEFAULT_BM = 256
-DEFAULT_BN = 512  # multiple of 32 (block) and 128 (lanes)
+DEFAULT_BM = 32
+DEFAULT_BN = 4096  # 128 lanes of 32-wide blocks
+LANES = 128
 
 
 def _quant_tile(x_tile: jax.Array, fmt: MXFormat, mode: str,
                 block: int) -> Tuple[jax.Array, jax.Array]:
     """Quantize one (BM, BN) f32 tile -> (codes u8 (BM,BN), scales u8
-    (BM, BN//block)).  Pure jnp: runs inside the Pallas kernel body and in
-    the reference oracle."""
+    (BM, BN//block)).  Every per-block quantity is computed on each lane of
+    its block, so no op reshapes the lane axis."""
     bm, bn = x_tile.shape
-    xg = x_tile.reshape(bm, bn // block, block)
-    sign, exp, man = _f32_fields(xg)
+    sign, exp, man = _f32_fields(x_tile)
     finite = exp != 0xFF
     is_nan = (~finite) & (man != 0)
     is_inf = (~finite) & (man == 0)
-    any_nan = jnp.any(is_nan, axis=-1)
-    any_inf = jnp.any(is_inf, axis=-1)
-    # step 1: comparator tree == lane max over the 32-wide sub-axis
-    ev_max = jnp.max(jnp.where(finite, exp, 0), axis=-1)
-    # step 2: shared scale
-    xscale = shared_scale(ev_max, fmt, mode, any_nan, any_inf)
-    xblk = jnp.broadcast_to(xscale[..., None].astype(jnp.int32), xg.shape)
+    # step 1: comparator tree == segment max over the block's lanes.  One
+    # max carries the NaN/Inf flags above the 8 exponent bits: a flagged
+    # block's scale is its marker whatever the exponent max, and NaN wins
+    # over Inf in both modes, so (key >= 256) serves as any_inf.
+    key = jnp.where(finite, exp, 0) | (is_inf.astype(jnp.int32) << 8) \
+        | (is_nan.astype(jnp.int32) << 9)
+    key = segment_max(key, block)
+    any_nan = key >= 512
+    any_inf = key >= 256
+    # step 2: shared scale, on every lane of its block
+    xscale = shared_scale(key & 0xFF, fmt, mode, any_nan, any_inf)
+    xblk = xscale.astype(jnp.int32)
     # step 3: private elements
     if fmt.is_int:
         codes = _quant_int8(sign, exp, man, xblk, mode)
@@ -66,11 +74,10 @@ def _quant_tile(x_tile: jax.Array, fmt: MXFormat, mode: str,
     else:
         codes = _quant_float_ocp(sign, exp, man, xblk, fmt)
     if mode == "paper":
-        blk_nan = jnp.broadcast_to(any_nan[..., None], xg.shape)
-        blk_inf = jnp.broadcast_to(any_inf[..., None], xg.shape)
-        codes = jnp.where(blk_inf, _marker_codes(sign, fmt, "inf"), codes)
-        codes = jnp.where(blk_nan, _marker_codes(sign, fmt, "nan"), codes)
-    return codes.reshape(bm, bn), xscale
+        codes = jnp.where(any_inf, _marker_codes(sign, fmt, "inf"), codes)
+        codes = jnp.where(any_nan, _marker_codes(sign, fmt, "nan"), codes)
+    scales = gather_lanes(xblk, bn // block, lambda c: c * block)
+    return codes, scales.astype(jnp.uint8)
 
 
 def _mx_quant_kernel(x_ref, codes_ref, scales_ref, *, fmt: MXFormat,
@@ -103,9 +110,14 @@ def mx_quantize_2d(x: jax.Array, spec=None, mode: Optional[str] = None,
 def _mx_quantize_2d(x: jax.Array, spec: QuantSpec, bm: int, bn: int,
                     interpret: bool) -> Tuple[jax.Array, jax.Array]:
     f, mode, block = spec.format, spec.mode, spec.block
+    if block & (block - 1):
+        raise ValueError(f"the converter kernel needs a power-of-two "
+                         f"block, got {spec}")
     m, n = x.shape
     bm_ = min(bm, max(1, m))
-    bn_ = min(bn, n) if n % block == 0 and n < bn else bn
+    # the whole padded row, or a multiple of LANES blocks (scale tile rule)
+    n_pad = -(-n // block) * block
+    bn_ = min(n_pad, -(-bn // (LANES * block)) * LANES * block)
     # pad to tile multiples (zeros are neutral for the exponent max)
     pm = (-m) % bm_
     pn = (-n) % bn_

@@ -1,10 +1,9 @@
 """Jit'd public wrappers over the Pallas kernels.
 
 Pallas execution mode is backend-aware (``kernels.backend``): interpret
-mode (kernel bodies executed in Python for correctness validation) on CPU,
-compiled Mosaic on TPU; ``REPRO_PALLAS_INTERPRET=0/1`` overrides either
-way.  Every wrapper below passes ``interpret=None`` through to the kernels,
-which resolve it per call.
+mode (kernel bodies executed as plain JAX for correctness validation) on
+the CPU, compiled Mosaic on the TPU.  Every wrapper below passes
+``interpret=None`` through to the kernels, which resolve it per call.
 """
 from __future__ import annotations
 
@@ -89,8 +88,7 @@ def mx_matmul_resident(a: jax.Array, w, impl: Optional[str] = None
         a2 = a.reshape(-1, a.shape[-1])
         if a2.shape[1] != w.kp:      # K was padded to a block multiple
             a2 = jnp.pad(a2, ((0, 0), (0, w.kp - a2.shape[1])))
-        from repro.kernels.backend import resolve_interpret
-        if resolve_interpret(None):
+        if backend.resolve_interpret(None):
             # interpret mode (CPU correctness path): per-grid-step overhead
             # dominates, so cover N in one tile and K in few — 5-10x faster
             # than VMEM-sized tiles at decode shapes, same results
@@ -100,8 +98,8 @@ def mx_matmul_resident(a: jax.Array, w, impl: Optional[str] = None
             out = _mm.mx_matmul_2d(a2, w.codes, w.scales, w.spec)
         return out.reshape(lead + (w.n,))
 
-    # supervised dispatch: a failed Pallas matmul degrades the op to the
-    # einsum path (logged once) instead of killing the serving process
+    # supervised dispatch: an injected launch fault degrades the op to the
+    # einsum path (logged once); a real kernel error propagates
     out = backend.supervised("mx_matmul", fused_path)
     return einsum_path() if out is None else out
 
@@ -128,15 +126,14 @@ def flash_attention_ctx(q: jax.Array, k: jax.Array, v: jax.Array,
     falls back to dense attention).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.dist import compat
-    from repro.dist.sharding import current_rules
+    from repro.dist.sharding import current_mesh, current_rules
     from repro.kernels.flash_attn import flash_attention
 
     rules = current_rules()
     if rules is None:
         return flash_attention(q, k, v, causal)
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty or not rules.get("model"):
+    mesh = current_mesh()
+    if mesh is None or not rules.get("model"):
         return flash_attention(q, k, v, causal)
     model_ax = rules["model"][0]
     batch_axes = rules.get("batch")
@@ -159,10 +156,10 @@ def flash_attention_ctx(q: jax.Array, k: jax.Array, v: jax.Array,
         return flash_attention(ql, ke, ve, causal)
 
     manual = set(a for a in ((batch_axes or ()) + (model_ax,)))
-    return compat.shard_map(body, mesh=mesh,
-                            in_specs=(qspec, kvspec, kvspec),
-                            out_specs=qspec, check_vma=False,
-                            axis_names=manual)(q, k, v)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(qspec, kvspec, kvspec),
+                         out_specs=qspec, check_vma=False,
+                         axis_names=manual)(q, k, v)
 
 
 def _prod(it):
@@ -178,8 +175,7 @@ def mx_decode_attention_ctx(q: jax.Array, cache: dict, pos, cfg):
     the local batch by shard_map.  Returns (B, 1, Hq, D) or None if the
     cache layout is unsupported (caller falls back to dequant + dense)."""
     from jax.sharding import PartitionSpec as P
-    from repro.dist import compat
-    from repro.dist.sharding import current_rules
+    from repro.dist.sharding import current_mesh, current_rules
     from repro.kernels.mx_decode_attn import mx_decode_attention
 
     kc, ks = cache["k_codes"], cache["k_scales"]
@@ -199,19 +195,19 @@ def mx_decode_attention_ctx(q: jax.Array, cache: dict, pos, cfg):
     rules = current_rules()
     if rules is None:
         return call(q, kc, ks, vc, vs, pos)
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = current_mesh()
+    if mesh is None:
         return call(q, kc, ks, vc, vs, pos)
     ba = rules.get("kv_batch") or ("data",)
     ba = tuple(a for a in ba if a in mesh.axis_names)
     if q.shape[0] % _prod(mesh.shape[a] for a in ba):
         return None
     bspec = P(ba, None, None, None)
-    return compat.shard_map(call, mesh=mesh,
-                            in_specs=(bspec, bspec, bspec, bspec, bspec,
-                                      P()),
-                            out_specs=bspec, check_vma=False,
-                            axis_names=set(ba))(q, kc, ks, vc, vs, pos)
+    return jax.shard_map(call, mesh=mesh,
+                         in_specs=(bspec, bspec, bspec, bspec, bspec,
+                                   P()),
+                         out_specs=bspec, check_vma=False,
+                         axis_names=set(ba))(q, kc, ks, vc, vs, pos)
 
 
 def mx_paged_decode_attention_ctx(q: jax.Array, pool: dict,
@@ -232,12 +228,9 @@ def mx_paged_decode_attention_ctx(q: jax.Array, pool: dict,
     here must be trace-stable — the mesh/rules are resolved from ambient
     context (constant across the window), the scalar-prefetch operands
     (block table, lengths) are scan-carried values, and the shard_map
-    region closes over no per-step Python state.  On jax 0.4.x,
-    dist.compat lowers shard_map-under-scan to full-manual mode
-    (see repro.dist.compat.shard_map)."""
+    region closes over no per-step Python state."""
     from jax.sharding import PartitionSpec as P
-    from repro.dist import compat
-    from repro.dist.sharding import current_rules
+    from repro.dist.sharding import current_mesh, current_rules
     from repro.kernels.mx_decode_attn import mx_paged_decode_attention
 
     kc, ks = pool["kc_pages"], pool["ks_pages"]
@@ -260,8 +253,8 @@ def mx_paged_decode_attention_ctx(q: jax.Array, pool: dict,
     rules = current_rules()
     if rules is None:
         return call(q, kc, ks, vc, vs, block_tables, lengths)
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = current_mesh()
+    if mesh is None:
         return call(q, kc, ks, vc, vs, block_tables, lengths)
     if rules.get("kv_pages"):
         return None                      # sharded pool: use gather fallback
@@ -271,9 +264,9 @@ def mx_paged_decode_attention_ctx(q: jax.Array, pool: dict,
         return None
     bspec = P(ba, None, None, None)
     pspec = P()                          # pool replicated per shard
-    return compat.shard_map(call, mesh=mesh,
-                            in_specs=(bspec, pspec, pspec, pspec, pspec,
-                                      P(ba, None), P(ba)),
-                            out_specs=bspec, check_vma=False,
-                            axis_names=set(ba))(q, kc, ks, vc, vs,
-                                                block_tables, lengths)
+    return jax.shard_map(call, mesh=mesh,
+                         in_specs=(bspec, pspec, pspec, pspec, pspec,
+                                   P(ba, None), P(ba)),
+                         out_specs=bspec, check_vma=False,
+                         axis_names=set(ba))(q, kc, ks, vc, vs,
+                                             block_tables, lengths)

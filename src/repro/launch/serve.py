@@ -90,6 +90,22 @@ def write_obs(args, eng, srv=None) -> None:
         print(f"[serve] wrote metrics snapshot -> {args.metrics_json}")
 
 
+def build_params(model, key, *, weight_resident: bool = False, mesh=None):
+    """Random serving parameters, built on the device.  ``weight_resident``
+    stores matmul weights in the policy's ``weights`` spec without the fp
+    model ever resident beside them (``Model.init_quantized``).  With a
+    ``mesh``, every leaf is placed by the decode-posture parameter
+    shardings as it is built, so no device holds the whole model."""
+    import jax
+
+    init = model.init_quantized if weight_resident else model.init
+    shardings = None
+    if mesh is not None:
+        from repro.launch.cells import shardings_for_params
+        shardings = shardings_for_params(jax.eval_shape(init, key), mesh)
+    return init(key, shardings)
+
+
 def parse_arrival(spec: str):
     """Parse an ``--arrival`` spec into ``(kind, params)``:
 
@@ -275,7 +291,9 @@ def main() -> None:
     import numpy as np
     import jax
 
-    from repro.dist import compat
+    from repro.launch.cache import use_compilation_cache
+    use_compilation_cache()
+
     from repro.dist.sharding import make_rules
     from repro.launch.mesh import make_test_mesh
     from repro.models import Model, load_config, load_reduced, \
@@ -312,7 +330,20 @@ def main() -> None:
               + (f" + {len(cfg.mx_table.overrides)} layer overrides"
                  if cfg.mx_table is not None else ""))
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
+    mesh = make_test_mesh(jax.device_count()) if args.shard else None
+    if args.weight_resident:
+        has_weights = cfg.mx.weights is not None or (
+            cfg.mx_table is not None
+            and any(cfg.layer_cfg(i).mx.weights is not None
+                    for i in range(cfg.n_layers)))
+        if not has_weights:
+            ap.error("--weight-resident needs a 'weights' role in the "
+                     "policy, e.g. --quant weights=e4m3@32:ocp")
+    # calibration reads fp weights, so an auto policy quantizes after it
+    resident_now = args.weight_resident and auto_budget is None
+    params = build_params(model, key, weight_resident=resident_now,
+                          mesh=mesh)
 
     if auto_budget is not None:
         # calibrate -> search -> apply (params are policy-independent,
@@ -341,28 +372,21 @@ def main() -> None:
               f"(budget {auto_budget:.4g} B/token)")
     if args.weight_resident:
         from repro.core.mx_weight import params_nbytes
-        has_weights = cfg.mx.weights is not None or (
-            cfg.mx_table is not None
-            and any(cfg.layer_cfg(i).mx.weights is not None
-                    for i in range(cfg.n_layers)))
-        if not has_weights:
-            ap.error("--weight-resident needs a 'weights' role in the "
-                     "policy, e.g. --quant weights=e4m3@32:ocp")
-        fp_bytes = params_nbytes(params)
-        params = model.quantize_weights(params)
+        fp_bytes = params_nbytes(jax.eval_shape(model.init, key))
+        if not resident_now:
+            params = model.quantize_weights(params)
         mx_bytes = params_nbytes(params)
         print(f"[serve] weight-resident: params {fp_bytes / 1e6:.2f} MB fp "
               f"-> {mx_bytes / 1e6:.2f} MB MX "
               f"({fp_bytes / max(mx_bytes, 1):.2f}x smaller)")
     rules = None
     mesh_ctx = contextlib.nullcontext()
-    if args.shard:
-        mesh = make_test_mesh(jax.device_count())
+    if mesh is not None:
         # decode posture: weights stay resident (no per-token ZeRO-3 gather)
         rules = make_rules(mesh.axis_names, fsdp_params=False,
                            paged_pool_sharded=args.shard_pool,
                            quant=cfg.mx)
-        mesh_ctx = compat.set_mesh(mesh)
+        mesh_ctx = jax.set_mesh(mesh)
         print(f"[serve] sharded over mesh {dict(mesh.shape)}")
     gen = GenerationConfig(max_new_tokens=args.new_tokens,
                            temperature=args.temperature)

@@ -72,6 +72,9 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.cache import use_compilation_cache
+    use_compilation_cache()
+
     from repro.data import DataConfig, SyntheticLM, make_batch_for
     from repro.models import Model, load_config, load_reduced
     from repro.models.config import QuantPolicy
@@ -149,7 +152,8 @@ def main() -> None:
         or cfg.mx.activations is not None
     if args.compressed_dp:
         ndev = jax.device_count()
-        mesh = jax.make_mesh((ndev,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((ndev,), ("data",))
         step = build_train_step_compressed_dp(
             model, opt_cfg, mesh=mesh, dp_axes=("data",),
             fake_quant=fake_quant)

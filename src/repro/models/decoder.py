@@ -46,7 +46,13 @@ def _layer_init(key, cfg: ModelConfig, moe_layer: bool) -> Dict[str, Any]:
     return p
 
 
-def init(key, cfg: ModelConfig) -> Dict[str, Any]:
+def init(key, cfg: ModelConfig, *,
+         quantized: bool = False) -> Dict[str, Any]:
+    """Random parameters from ``key``.  ``quantized=True`` returns
+    ``quantize_weights(init(key, cfg), cfg)`` built one layer at a time:
+    under jit each layer's fp weights are drawn and quantized inside a
+    ``lax.map`` step, so the fp model is never resident beside its MX
+    copy."""
     vp = padded_vocab(cfg)
     d = cfg.d_model
     dt = L.dtype_of(cfg)
@@ -54,7 +60,19 @@ def init(key, cfg: ModelConfig) -> Dict[str, Any]:
     n_scan = cfg.n_layers - cfg.n_dense_layers
     lkeys = jax.random.split(keys[0], n_scan)
     moe_layer = cfg.n_experts > 0
-    layers = jax.vmap(lambda k: _layer_init(k, cfg, moe_layer))(lkeys)
+
+    def layer(k, cfg_i, moe):
+        lp = _layer_init(k, cfg, moe)
+        return _quantize_layer_tree(lp, cfg_i.mx.weights) if quantized \
+            else lp
+
+    if not quantized:
+        layers = jax.vmap(lambda k: _layer_init(k, cfg, moe_layer))(lkeys)
+    elif cfg.mx_table is None:
+        layers = jax.lax.map(lambda k: layer(k, cfg, moe_layer), lkeys)
+    else:
+        layers = [layer(k, cfg_i, moe_layer)
+                  for k, cfg_i in zip(lkeys, _scan_cfgs(cfg))]
     params = {
         "embed": L.embed_init(keys[1], vp, d, dt),
         "layers": layers,
@@ -63,7 +81,7 @@ def init(key, cfg: ModelConfig) -> Dict[str, Any]:
     if cfg.n_dense_layers:
         dkeys = jax.random.split(keys[2], cfg.n_dense_layers)
         params["dense_layers"] = [
-            _layer_init(k, cfg, moe_layer=False) for k in dkeys]
+            layer(k, cfg.layer_cfg(i), False) for i, k in enumerate(dkeys)]
     if not cfg.tie_embeddings:
         params["lm_head"] = L.dense_init(keys[3], d, vp, dt)
     return params
@@ -173,6 +191,19 @@ def _quantize_layer_tree(lp, spec):
     return walk(lp)
 
 
+def _require_gqa_weights(cfg: ModelConfig) -> None:
+    if cfg.mla:
+        raise NotImplementedError(
+            "weight-resident storage covers the GQA decoder family "
+            "(MLA projections are not routed through MXWeight yet)")
+
+
+def init_quantized(key, cfg: ModelConfig) -> Dict[str, Any]:
+    """``quantize_weights(init(key, cfg), cfg)``, one layer at a time."""
+    _require_gqa_weights(cfg)
+    return init(key, cfg, quantized=True)
+
+
 def quantize_weights(params, cfg: ModelConfig):
     """Convert matmul weights to weight-resident MXWeight storage.
 
@@ -184,10 +215,7 @@ def quantize_weights(params, cfg: ModelConfig):
     whose table entry has no weights role stay fp) — the unrolled walks
     already consume lists via ``_scan_layer_params``.
     """
-    if cfg.mla:
-        raise NotImplementedError(
-            "weight-resident storage covers the GQA decoder family "
-            "(MLA projections are not routed through MXWeight yet)")
+    _require_gqa_weights(cfg)
     out = dict(params)
     if cfg.mx_table is not None:
         out["layers"] = [

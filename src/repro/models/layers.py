@@ -457,8 +457,8 @@ def attention_paged_decode(p: Params, x: jax.Array, cfg: ModelConfig, *,
     if cfg.mx.kv_key is not None and cfg.attn_impl == "flash":
         from repro.kernels import backend
         from repro.kernels.ops import mx_paged_decode_attention_ctx
-        # supervised dispatch: a failed (or degraded) kernel returns None
-        # and the dense gather path below serves token-identically
+        # supervised dispatch: a degraded kernel (injected fault) returns
+        # None and the dense gather path below serves token-identically
         out = backend.supervised("paged_attn", mx_paged_decode_attention_ctx,
                                  q, pool, block_tables, lengths, cfg)
     if out is None:

@@ -54,8 +54,19 @@ class Model:
         self.cfg = cfg
         self.mod = family_module(cfg)
 
-    def init(self, key):
-        return self.mod.init(key, self.cfg)
+    def init(self, key, out_shardings=None):
+        """Random parameters, built under jit: each weight's RNG draw and
+        dtype cast fuse, so no f32 copy of a stacked weight reaches device
+        memory.  ``out_shardings`` places each leaf as it is built."""
+        return jax.jit(self.mod.init, static_argnums=1,
+                       out_shardings=out_shardings)(key, self.cfg)
+
+    def init_quantized(self, key, out_shardings=None):
+        """``quantize_weights(init(key))`` without the fp model ever
+        resident beside its MX copy (decoder family; see decoder.init)."""
+        self._require_decoder()
+        return jax.jit(self.mod.init_quantized, static_argnums=1,
+                       out_shardings=out_shardings)(key, self.cfg)
 
     def forward(self, params, batch: Dict[str, jax.Array], *,
                 fake_quant: bool = False):
@@ -96,12 +107,14 @@ class Model:
         decoder.quantize_weights).  Serve the result as-is — ``dense()``
         routes MXWeight operands through the fused dequant-in-VMEM
         matmul kernel."""
-        cfg = self.cfg
-        if cfg.family != "decoder":
+        self._require_decoder()
+        return self.mod.quantize_weights(params, self.cfg)
+
+    def _require_decoder(self) -> None:
+        if self.cfg.family != "decoder":
             raise NotImplementedError(
-                f"{cfg.name}: weight-resident storage covers the decoder "
-                "family")
-        return self.mod.quantize_weights(params, cfg)
+                f"{self.cfg.name}: weight-resident storage covers the "
+                "decoder family")
 
     def forward_calib(self, params, batch: Dict[str, jax.Array]):
         """Instrumented forward for repro.calib: (logits, aux, taps) with

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.grad_compress import mx_allreduce_tree
-from repro.dist import compat
 from repro.models.registry import Model
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 
@@ -145,11 +144,9 @@ def build_train_step_compressed_dp(model: Model, opt_cfg: AdamWConfig, *,
                      {"loss": rep, "ce": rep, "aux": rep, "grad_norm": rep,
                       "lr": rep})
         # manual over the DP axes only; any "model" axis stays automatic
-        # (on jax 0.4.x compat.shard_map makes it manual-replicated
-        # instead — partial-auto there crashes the SPMD partitioner)
-        fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False,
-                              axis_names=set(dp))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False,
+                           axis_names=set(dp))
         return fn(params, opt_state, batch, step)
 
     return train_step

@@ -34,17 +34,17 @@ def test_row_parallel_mx_gather_divisibility():
         import jax.numpy as jnp
         from repro.core import MXWeight, QuantSpec
         from repro.core.convert import mx_quantize
-        from repro.dist import compat
         from repro.dist.sharding import make_rules, use_rules
+        from repro.launch.mesh import make_mesh
         from repro.models.layers import dense
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = make_rules(("data", "model"), fsdp_params=True)
         spec = QuantSpec("e4m3", "ocp", 32, True)
         rng = np.random.default_rng(0)
         fn = jax.jit(lambda x, w: dense(x, w, tp="row"))
 
-        with compat.set_mesh(mesh), use_rules(rules):
+        with jax.set_mesh(mesh), use_rules(rules):
             # K=32 -> K//block=1 scale row, model axis 2: codes' K divides,
             # scales' K//block does not -> loud error naming the sizes
             w_bad = rng.normal(size=(32, 16)).astype(np.float32)
@@ -172,7 +172,6 @@ def test_sharded_fused_decode_token_identical():
     step — is token-identical to single-device per-step decode."""
     run_devprog("""
         import numpy as np, jax
-        from repro.dist import compat
         from repro.dist.sharding import make_rules
         from repro.launch.mesh import make_test_mesh
         from repro.models import Model, load_reduced
@@ -192,7 +191,7 @@ def test_sharded_fused_decode_token_identical():
         mesh = make_test_mesh(jax.device_count())
         rules = make_rules(mesh.axis_names, fsdp_params=False,
                            quant=cfg.mx)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             eng = ContinuousBatchingEngine(
                 model, params, max_slots=2, page_size=8, max_len=19,
                 rules=rules, gen=gen, sync_every=4)

@@ -49,7 +49,8 @@ def test_dryrun_small_mesh_lower_compile():
 
         # shrink: monkeypatch the config loader to the reduced config with
         # dims divisible by the 2x4 test mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         real_load = R.load_config
         def tiny(arch, **over):
             cfg = R.load_reduced(arch, dtype="bfloat16",

@@ -70,9 +70,10 @@ def test_kernel_nonfinite_markers():
 
 def test_kernel_tile_boundary_independence():
     """Same data, different tile shapes -> identical codes (no cross-tile
-    state leaks; blocks are 32-aligned within every legal tile)."""
-    x = _rand((64, 1024), jnp.float32, seed=4)
-    c1, s1 = mx_quantize_2d(x, fmt="e3m2", mode="ocp", bm=16, bn=256)
-    c2, s2 = mx_quantize_2d(x, fmt="e3m2", mode="ocp", bm=64, bn=1024)
+    state leaks; blocks are 32-aligned within every legal tile).  Column
+    tiles are whole rows or multiples of 128 blocks (4096 lanes)."""
+    x = _rand((64, 8192), jnp.float32, seed=4)
+    c1, s1 = mx_quantize_2d(x, fmt="e3m2", mode="ocp", bm=16, bn=4096)
+    c2, s2 = mx_quantize_2d(x, fmt="e3m2", mode="ocp", bm=64, bn=8192)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))

@@ -376,6 +376,25 @@ def test_kernel_fail_degrades_to_dense(caplog):
         np.testing.assert_array_equal(out[r], want[r])
 
 
+def test_real_kernel_error_propagates():
+    """Only an injected fault degrades an op: an exception raised by the
+    kernel itself reaches the caller, and the op stays on the kernel path
+    (a kernel the chip's compiler refuses must fail the run, not serve it
+    at dense speed)."""
+    backend.reset_degradation()
+
+    def broken():
+        raise ValueError("Mosaic refused the block shape")
+
+    with pytest.raises(ValueError, match="Mosaic refused"):
+        backend.supervised("paged_attn", broken)
+    assert not backend.is_degraded("paged_attn")
+    backend.inject_failure("paged_attn")
+    assert backend.supervised("paged_attn", broken) is None
+    assert backend.is_degraded("paged_attn")
+    backend.reset_degradation()
+
+
 def test_combined_plan_is_deterministic(mixed):
     """One plan exercising four sites at once: the healthy request is
     token-identical to the clean run, exactly the targeted requests are

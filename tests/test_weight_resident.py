@@ -137,6 +137,30 @@ def test_policy_table_mixed_layer_quantization():
     assert isinstance(qp2["layers"][1]["attn"]["wq"], jax.Array)
 
 
+@pytest.mark.parametrize("policy", [
+    "weights=e4m3@32:ocp", "weights=e2m1@32:ocp", "table"])
+def test_init_quantized_matches_quantize_weights(policy):
+    """Building MX weights layer by layer (the fp model never resident
+    beside them) gives exactly ``quantize_weights(init(key))``: uniform
+    stacked layers, packed sub-byte codes, and a per-layer table whose
+    second layer stays fp."""
+    if policy == "table":
+        table = PolicyTable(default=QuantPolicy.parse("weights=e2m1@32:ocp"),
+                            overrides=((1, QuantPolicy()),))
+        cfg = apply_policy_table(load_reduced("chatglm3_6b"), table)
+    else:
+        cfg = load_reduced("chatglm3_6b", mx=QuantPolicy.parse(policy))
+    model = Model(cfg)
+    key = jax.random.PRNGKey(3)
+    want = model.quantize_weights(model.init(key))
+    got = model.init_quantized(key)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 # ------------------------------------------------- engine-level end-to-end
 def test_engine_token_identity_and_weight_pool():
     """Weight-resident serving must emit the same tokens as serving the
