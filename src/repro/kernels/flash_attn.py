@@ -112,6 +112,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn",
     )(q, k, v)
     return out[:, :sq]
 

@@ -266,6 +266,7 @@ def _mx_paged_decode_attention(q, kc_pool, ks_pool, vc_pool, vs_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, 1, d), q.dtype),
         interpret=interpret,
+        name="mx_paged_decode_attn",
     )(block_tables, lengths, qt, kc_pool, ks_pool, vc_pool, vs_pool)
     # analytic cost: the gathered pages (quantized bytes), not the pool
     s = np_max * page

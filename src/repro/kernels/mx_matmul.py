@@ -148,5 +148,6 @@ def _mx_matmul_2d(a: jax.Array, codes: jax.Array, scales: jax.Array,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="mx_matmul",
     )(ap, cp, sp)
     return out[:m, :n]

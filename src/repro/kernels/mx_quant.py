@@ -139,5 +139,6 @@ def _mx_quantize_2d(x: jax.Array, spec: QuantSpec, bm: int, bn: int,
             jax.ShapeDtypeStruct((mp, np_ // block), jnp.uint8),
         ],
         interpret=interpret,
+        name="mx_quant",
     )(xp)
     return codes[:m, :n], scales[:m, : (n + block - 1) // block]

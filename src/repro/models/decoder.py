@@ -20,7 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.dist.sharding import logical
+from repro.models import health as H
 from repro.models import layers as L
+from repro.models import scopes
 from repro.models.config import ModelConfig
 
 VOCAB_PAD = 512
@@ -91,7 +93,8 @@ def _block(lp, x, cfg: ModelConfig, *, positions, cache=None, cache_pos=None,
            moe_layer: bool, fake_quant: bool,
            paged=None, paged_prefill=None,
            tap=None) -> Tuple[jax.Array, Any, jax.Array]:
-    h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+    with scopes.scope("attn_qkv"):
+        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
     s = x.shape[1]
     if paged_prefill is not None:
         block_tables, starts, prompt_lens = paged_prefill
@@ -117,20 +120,24 @@ def _block(lp, x, cfg: ModelConfig, *, positions, cache=None, cache_pos=None,
         a, new_cache = L.attention(lp["attn"], h, cfg, positions=positions,
                                    cache=cache, cache_pos=cache_pos,
                                    fake_quant=fake_quant, tap=tap)
-    x = x + a
-    h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if tap is not None:
-        # the activations role quantizes matmul inputs; the two post-norm
-        # hidden states are the layer's representative matmul inputs
-        tap["activations"] = jnp.concatenate([h, h2], axis=1)
-    aux = jnp.zeros((), jnp.float32)
-    if moe_layer:
-        m, aux = L.moe(lp["moe"], h2, cfg, fake_quant)
-    else:
-        m = L.mlp(lp["mlp"], h2, cfg, fake_quant)
-    return x + m, new_cache, aux
+    with scopes.scope("attn_out"):
+        x = x + a
+    with scopes.scope("mlp"):
+        h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        if tap is not None:
+            # the activations role quantizes matmul inputs; the two
+            # post-norm hidden states are the layer's representative
+            # matmul inputs
+            tap["activations"] = jnp.concatenate([h, h2], axis=1)
+        aux = jnp.zeros((), jnp.float32)
+        if moe_layer:
+            m, aux = L.moe(lp["moe"], h2, cfg, fake_quant)
+        else:
+            m = L.mlp(lp["mlp"], h2, cfg, fake_quant)
+        return x + m, new_cache, aux
 
 
+@scopes.scoped("embed")
 def _embed(params, cfg, tokens, prefix_embeds):
     x = jnp.take(params["embed"], tokens, axis=0).astype(L.dtype_of(cfg))
     if prefix_embeds is not None:
@@ -138,6 +145,7 @@ def _embed(params, cfg, tokens, prefix_embeds):
     return logical(x, "batch", None, None)
 
 
+@scopes.scoped("head")
 def _head(params, cfg, x):
     x = L.rms_norm(x, params["norm_f"], cfg.norm_eps)
     head = params.get("lm_head")
@@ -411,6 +419,7 @@ def forward_calib(params, tokens, cfg: ModelConfig, *, prefix_embeds=None):
     return _head(params, cfg, x), jnp.mean(jnp.stack(auxs)), taps
 
 
+@scopes.scoped("sample")
 def sample_tokens(logits, keys, temperature: float):
     """Sample one token per slot inside the jitted decode path.
 
@@ -431,6 +440,7 @@ def sample_tokens(logits, keys, temperature: float):
     return keys, nxt
 
 
+@scopes.scoped("kv_write")
 def scatter_prefill(cfg: ModelConfig, pool, cache, page_ids):
     """Scatter a batched contiguous prefill cache (G requests padded to the
     same page-multiple length) into the paged pools; one donated scatter
@@ -594,7 +604,7 @@ def paged_decode_multi_step(params, token, cache, block_tables, lengths,
         last = logits[:, -1, :vocab]
         keys, nxt = sample_tokens(last, keys, temperature)
         if health:
-            bad = bad | (~jnp.all(jnp.isfinite(last), axis=-1) & ~done)
+            bad = bad | (H.nonfinite_rows(last) & ~done)
         nxt = jnp.where(done, tok, nxt)
         lengths = jnp.where(done, lengths, lengths + 1)
         remaining = jnp.where(done, remaining, remaining - 1)

@@ -27,7 +27,15 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.formats import poison_threshold
+from repro.models import scopes
 from repro.models.layers import paged_page_size
+
+
+@scopes.scoped("health")
+def nonfinite_rows(logits):
+    """(B,) bool: True where a row of ``logits`` (B, vocab) holds a
+    non-finite value — the finite-logits half of the guard."""
+    return ~jnp.all(jnp.isfinite(logits), axis=-1)
 
 
 def _group_poison(group, page_tables, live, kk, kv):
@@ -52,6 +60,7 @@ def _group_poison(group, page_tables, live, kk, kv):
     return flags
 
 
+@scopes.scoped("health")
 def slot_scale_poison(pool, page_tables, lengths, cfg):
     """Per-slot MX-block poison detection: (B,) bool, True where any
     SCALE_NAN/SCALE_INF marker byte sits inside the slot's *live* cache

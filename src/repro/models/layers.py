@@ -27,6 +27,7 @@ from repro.core.spec import QuantPolicy, QuantSpec
 from repro.dist.sharding import (bf16_matmul_out_enabled, logical,
                                  model_axis_size, weight_gather_enabled,
                                  weight_gather_mode)
+from repro.models import scopes
 from repro.models.config import ModelConfig
 
 Params = Dict[str, Any]
@@ -231,6 +232,7 @@ def _kv_dequant(codes: jax.Array, scales: jax.Array, spec: QuantSpec,
     return mx_dequantize(mx).astype(dtype)
 
 
+@scopes.scoped("kv_write")
 def cache_write(cache, k: jax.Array, v: jax.Array, pos, cfg: ModelConfig):
     """Write k/v (B, s, n_kv, hd) into the cache at position ``pos``.
 
@@ -256,6 +258,7 @@ def cache_write(cache, k: jax.Array, v: jax.Array, pos, cfg: ModelConfig):
                 cache["v"].dtype), idx)}
 
 
+@scopes.scoped("kv_read")
 def cache_read(cache, cfg: ModelConfig, dtype, hd: Optional[int] = None):
     if cfg.mx.kv_key is not None:
         k = _kv_dequant(cache["k_codes"], cache["k_scales"], cfg.mx.kv_key,
@@ -316,6 +319,7 @@ PAGED_POOL_KEYS = {
 }
 
 
+@scopes.scoped("kv_write")
 def paged_cache_scatter(pool, cache, page_ids, cfg: ModelConfig):
     """Scatter a *batched* contiguous prefill cache into the page pool.
 
@@ -347,6 +351,7 @@ def paged_cache_scatter(pool, cache, page_ids, cfg: ModelConfig):
     return out
 
 
+@scopes.scoped("kv_write")
 def paged_cache_write(pool, k: jax.Array, v: jax.Array, pages: jax.Array,
                       offsets: jax.Array, cfg: ModelConfig):
     """Scatter one token per slot into the page pool.
@@ -373,6 +378,7 @@ def paged_cache_write(pool, k: jax.Array, v: jax.Array, pages: jax.Array,
                 v[:, 0].astype(dt)), "kv_pages", None, None, None)}
 
 
+@scopes.scoped("kv_write")
 def paged_cache_write_tokens(pool, k: jax.Array, v: jax.Array,
                              pages: jax.Array, offsets: jax.Array,
                              cfg: ModelConfig):
@@ -403,6 +409,7 @@ def paged_cache_write_tokens(pool, k: jax.Array, v: jax.Array,
                 v.astype(dt)), "kv_pages", None, None, None)}
 
 
+@scopes.scoped("kv_read")
 def paged_cache_gather(pool, block_tables: jax.Array, cfg: ModelConfig,
                        dtype, hd: int) -> Tuple[jax.Array, jax.Array]:
     """Gather a slot-major contiguous (B, max_pages*page, n_kv, hd) K/V view
@@ -440,17 +447,19 @@ def attention_paged_decode(p: Params, x: jax.Array, cfg: ModelConfig, *,
     b, s, d = x.shape                          # s == 1
     hd, nh, nkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     mx = cfg.mx
-    q = dense(x, p["wq"], mx, fake_quant)
-    q = logical(q, "batch", None, "model").reshape(b, s, nh, hd)
-    k = dense(x, p["wk"], mx, fake_quant).reshape(b, s, nkv, hd)
-    v = dense(x, p["wv"], mx, fake_quant).reshape(b, s, nkv, hd)
-    positions = lengths[:, None]
-    cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin, cfg.rope_frac)
-    k = apply_rope(k, cos, sin, cfg.rope_frac)
+    with scopes.scope("attn_qkv"):
+        q = dense(x, p["wq"], mx, fake_quant)
+        q = logical(q, "batch", None, "model").reshape(b, s, nh, hd)
+        k = dense(x, p["wk"], mx, fake_quant).reshape(b, s, nkv, hd)
+        v = dense(x, p["wv"], mx, fake_quant).reshape(b, s, nkv, hd)
+        positions = lengths[:, None]
+        cos, sin = rope_tables(positions, hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin, cfg.rope_frac)
+        k = apply_rope(k, cos, sin, cfg.rope_frac)
     page = paged_page_size(pool)
-    pages = jnp.take_along_axis(
-        block_tables, (lengths // page)[:, None], axis=1)[:, 0]
+    with scopes.scope("kv_write"):
+        pages = jnp.take_along_axis(
+            block_tables, (lengths // page)[:, None], axis=1)[:, 0]
     pool = paged_cache_write(pool, k, v, pages, lengths % page, cfg)
     q = logical(q, "kv_batch", None, None, None)
     out = None
@@ -459,8 +468,10 @@ def attention_paged_decode(p: Params, x: jax.Array, cfg: ModelConfig, *,
         from repro.kernels.ops import mx_paged_decode_attention_ctx
         # supervised dispatch: a degraded kernel (injected fault) returns
         # None and the dense gather path below serves token-identically
-        out = backend.supervised("paged_attn", mx_paged_decode_attention_ctx,
-                                 q, pool, block_tables, lengths, cfg)
+        with scopes.scope("attn_core"):
+            out = backend.supervised(
+                "paged_attn", mx_paged_decode_attention_ctx, q, pool,
+                block_tables, lengths, cfg)
     if out is None:
         ka, va = paged_cache_gather(pool, block_tables, cfg, x.dtype, hd)
         # keep the gathered view slot-sharded (decode reads stay local);
@@ -471,8 +482,9 @@ def attention_paged_decode(p: Params, x: jax.Array, cfg: ModelConfig, *,
         mask = jnp.arange(sk)[None, None, None, None, :] \
             <= lengths[:, None, None, None, None]
         out = _sdpa_gqa(q, ka, va, mask)
-    out = out.reshape(b, s, nh * hd)
-    out = dense(out, p["wo"], mx, fake_quant, tp="row")
+    with scopes.scope("attn_out"):
+        out = out.reshape(b, s, nh * hd)
+        out = dense(out, p["wo"], mx, fake_quant, tp="row")
     return logical(out, "batch", None, None), pool
 
 
@@ -501,21 +513,23 @@ def attention_paged_prefill(p: Params, x: jax.Array, cfg: ModelConfig, *,
     g, s, d = x.shape
     hd, nh, nkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     mx = cfg.mx
-    q = dense(x, p["wq"], mx, fake_quant)
-    q = logical(q, "batch", None, "model").reshape(g, s, nh, hd)
-    k = dense(x, p["wk"], mx, fake_quant).reshape(g, s, nkv, hd)
-    v = dense(x, p["wv"], mx, fake_quant).reshape(g, s, nkv, hd)
-    positions = starts[:, None] + jnp.arange(s)[None, :]        # (G, S)
-    cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin, cfg.rope_frac)
-    k = apply_rope(k, cos, sin, cfg.rope_frac)
+    with scopes.scope("attn_qkv"):
+        q = dense(x, p["wq"], mx, fake_quant)
+        q = logical(q, "batch", None, "model").reshape(g, s, nh, hd)
+        k = dense(x, p["wk"], mx, fake_quant).reshape(g, s, nkv, hd)
+        v = dense(x, p["wv"], mx, fake_quant).reshape(g, s, nkv, hd)
+        positions = starts[:, None] + jnp.arange(s)[None, :]    # (G, S)
+        cos, sin = rope_tables(positions, hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin, cfg.rope_frac)
+        k = apply_rope(k, cos, sin, cfg.rope_frac)
     page = paged_page_size(pool)
     np_max = block_tables.shape[1]
-    valid = positions < prompt_lens[:, None]
-    page_idx = jnp.clip(positions // page, 0, np_max - 1)
-    pages = jnp.where(valid,
-                      jnp.take_along_axis(block_tables, page_idx, axis=1),
-                      trash_page)
+    with scopes.scope("kv_write"):
+        valid = positions < prompt_lens[:, None]
+        page_idx = jnp.clip(positions // page, 0, np_max - 1)
+        pages = jnp.where(
+            valid, jnp.take_along_axis(block_tables, page_idx, axis=1),
+            trash_page)
     pool = paged_cache_write_tokens(pool, k, v, pages, positions % page,
                                     cfg)
     q = logical(q, "kv_batch", None, None, None)
@@ -526,8 +540,9 @@ def attention_paged_prefill(p: Params, x: jax.Array, cfg: ModelConfig, *,
     mask = jnp.arange(sk)[None, None, None, None, :] \
         <= positions[:, None, None, :, None]
     out = _sdpa_gqa(q, ka, va, mask)
-    out = out.reshape(g, s, nh * hd)
-    out = dense(out, p["wo"], mx, fake_quant, tp="row")
+    with scopes.scope("attn_out"):
+        out = out.reshape(g, s, nh * hd)
+        out = dense(out, p["wo"], mx, fake_quant, tp="row")
     return logical(out, "batch", None, None), pool
 
 
@@ -547,6 +562,7 @@ def attn_init(key, cfg: ModelConfig, d: Optional[int] = None) -> Params:
     }
 
 
+@scopes.scoped("attn_core")
 def _sdpa_gqa(q, k, v, mask) -> jax.Array:
     """Grouped-query attention without materializing repeated K/V.
 
@@ -583,19 +599,20 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
     hd, nh, nkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     mx = cfg.mx
     quant_prefill = False
-    q = dense(x, p["wq"], mx, fake_quant)
-    q = logical(q, "batch", None, "model")
-    q = q.reshape(b, s, nh, hd)
-    if kv_override is None:
-        k = dense(x, p["wk"], mx, fake_quant).reshape(b, s, nkv, hd)
-        v = dense(x, p["wv"], mx, fake_quant).reshape(b, s, nkv, hd)
-        cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-        q = apply_rope(q, cos, sin, cfg.rope_frac)
-        k = apply_rope(k, cos, sin, cfg.rope_frac)
-        if tap is not None:
-            tap["k"], tap["v"] = k, v
-    else:
-        k, v = kv_override
+    with scopes.scope("attn_qkv"):
+        q = dense(x, p["wq"], mx, fake_quant)
+        q = logical(q, "batch", None, "model")
+        q = q.reshape(b, s, nh, hd)
+        if kv_override is None:
+            k = dense(x, p["wk"], mx, fake_quant).reshape(b, s, nkv, hd)
+            v = dense(x, p["wv"], mx, fake_quant).reshape(b, s, nkv, hd)
+            cos, sin = rope_tables(positions, hd, cfg.rope_theta)
+            q = apply_rope(q, cos, sin, cfg.rope_frac)
+            k = apply_rope(k, cos, sin, cfg.rope_frac)
+            if tap is not None:
+                tap["k"], tap["v"] = k, v
+        else:
+            k, v = kv_override
     new_cache = cache
     if cache is not None and kv_override is None:
         new_cache = cache_write(cache, k, v,
@@ -611,11 +628,13 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
                 # fused path: the u8 cache never leaves HBM un-quantized —
                 # dequant happens in VMEM inside the kernel
                 from repro.kernels.ops import mx_decode_attention_ctx
-                ofused = mx_decode_attention_ctx(q, new_cache, cache_pos,
-                                                 cfg)
+                with scopes.scope("attn_core"):
+                    ofused = mx_decode_attention_ctx(q, new_cache,
+                                                     cache_pos, cfg)
                 if ofused is not None:
-                    out = ofused.reshape(b, s, nh * hd)
-                    out = dense(out, p["wo"], mx, fake_quant, tp="row")
+                    with scopes.scope("attn_out"):
+                        out = ofused.reshape(b, s, nh * hd)
+                        out = dense(out, p["wo"], mx, fake_quant, tp="row")
                     return logical(out, "batch", None, None), new_cache
             k, v = cache_read(new_cache, cfg, x.dtype, hd)
             k = logical(k, "kv_batch", None, None, None)
@@ -656,11 +675,13 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
     if cfg.attn_impl == "flash" and causal and s > 1 \
             and s == k.shape[1] and not quant_prefill:
         from repro.kernels.ops import flash_attention_ctx
-        out = flash_attention_ctx(q, k, v, causal=True)
+        with scopes.scope("attn_core"):
+            out = flash_attention_ctx(q, k, v, causal=True)
     if out is None:
         out = _sdpa_gqa(q, k, v, mask)
-    out = out.reshape(b, s, nh * hd)
-    out = dense(out, p["wo"], mx, fake_quant, tp="row")
+    with scopes.scope("attn_out"):
+        out = out.reshape(b, s, nh * hd)
+        out = dense(out, p["wo"], mx, fake_quant, tp="row")
     return logical(out, "batch", None, None), new_cache
 
 

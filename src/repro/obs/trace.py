@@ -33,10 +33,12 @@ rid = tid rid+1) with ``B``/``E``/``i`` phases — load the file in
 Perfetto / ``chrome://tracing`` to see queueing, prefill, decode
 windows, preemptions, and retries per request on a common timeline.
 
-``annotate=True`` additionally wraps engine-track spans in
-``jax.profiler.TraceAnnotation`` so device profiles line up with engine
-spans (request tracks interleave and cannot nest globally, so they are
-never annotated).
+These spans are recorded after the fact from host stamps, so they do
+not reach the profiler's clock.  For device profiles the engine opens
+live ``jax.profiler.TraceAnnotation``s of its own around its host phases
+(``engine.step``, ``engine.admit``, ``engine.prefill``,
+``engine.decode_window``, ``engine.fanout``; see
+``ContinuousBatchingEngine.step``), tracer or not.
 """
 from __future__ import annotations
 
@@ -50,26 +52,22 @@ EVENT_FIELDS = ("seq", "ph", "name", "cat", "rid", "t_us")
 
 
 class _Span:
-    __slots__ = ("name", "t_us", "annotation")
+    __slots__ = ("name", "t_us")
 
-    def __init__(self, name: str, t_us: int, annotation=None):
+    def __init__(self, name: str, t_us: int):
         self.name = name
         self.t_us = t_us
-        self.annotation = annotation
 
 
 class Tracer:
     """Span recorder for one serving process.
 
     ``meta`` rides the JSONL header (seed, model, policy — anything the
-    launcher wants alongside the events); ``annotate`` wraps
-    engine-track spans in ``jax.profiler.TraceAnnotation``.
+    launcher wants alongside the events).
     """
 
-    def __init__(self, meta: Optional[Dict[str, Any]] = None,
-                 annotate: bool = False):
+    def __init__(self, meta: Optional[Dict[str, Any]] = None):
         self.meta = dict(meta or {})
-        self.annotate = bool(annotate)
         self.t0 = perf_counter()
         self.events: List[Dict[str, Any]] = []
         self._stacks: Dict[Optional[int], List[_Span]] = {}
@@ -95,10 +93,7 @@ class Tracer:
         """Open a span on ``rid``'s track (None = the engine track) at
         ``ts`` (a perf_counter stamp; defaults to now)."""
         t_us = self._t_us(ts)
-        ann = None
-        if self.annotate and rid is None:
-            ann = _annotation(name)
-        self._stacks.setdefault(rid, []).append(_Span(name, t_us, ann))
+        self._stacks.setdefault(rid, []).append(_Span(name, t_us))
         self._emit("B", name, cat, rid, t_us, args or None)
 
     def end(self, name: str, cat: str = "engine",
@@ -116,8 +111,6 @@ class Tracer:
             raise ValueError(
                 f"end({name!r}): innermost open span on track {rid} "
                 f"is {top.name!r}")
-        if top.annotation is not None:
-            top.annotation.__exit__(None, None, None)
         self._emit("E", name, cat, rid, max(self._t_us(ts), top.t_us),
                    args or None)
 
@@ -190,18 +183,6 @@ class Tracer:
             json.dump({"traceEvents": chrome_events(self.events),
                        "displayTimeUnit": "ms",
                        "otherData": self.meta}, f)
-
-
-def _annotation(name: str):
-    """Enter a jax.profiler.TraceAnnotation (None when jax or the
-    profiler is unavailable — the shim is strictly optional)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:       # pragma: no cover - depends on jax build
-        return None
-    ann = TraceAnnotation(name)
-    ann.__enter__()
-    return ann
 
 
 def _tid(rid: Optional[int]) -> int:

@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.mx_weight import params_nbytes
 from repro.dist.sharding import use_rules
@@ -209,6 +210,9 @@ class ContinuousBatchingEngine:
                          the stamps the engine already takes — zero
                          extra host syncs, token-identical on/off
                          (asserted in tests/test_obs_identity.py).
+                         Its spans are written after the fact and never
+                         reach the profiler's clock; the live
+                         ``engine.*`` annotations ``step`` opens do.
     ``obs_interval``   — sample the MX-health gauges (``mx.*``: scale
                          poison markers, saturation/clip and underflow
                          rates per KV role) every N sync windows; 0
@@ -299,6 +303,9 @@ class ContinuousBatchingEngine:
             "engine.steps", "device decode steps (incl. masked tail)")
         self._c_syncs = m.counter(
             "engine.syncs", "host sync points (fused windows run)")
+        self._c_live_steps = m.counter(
+            "engine.live_slot_steps",
+            "slot-steps of decode windows spent on live requests")
         self._c_generated = m.counter(
             "engine.generated_tokens", "tokens emitted to requests")
         self._c_prefill_tokens = m.counter(
@@ -358,7 +365,7 @@ class ContinuousBatchingEngine:
                 keys, first = sample_tokens(last, keys, temperature)
                 if not health_on:
                     return first, keys, pool, jnp.zeros(g, bool)
-                bad = ~jnp.all(jnp.isfinite(last), axis=-1)
+                bad = H.nonfinite_rows(last)
                 bad = bad | H.slot_scale_poison(pool, page_ids, lens, cfg)
                 return first, keys, pool, bad
 
@@ -378,7 +385,7 @@ class ContinuousBatchingEngine:
                 keys, first = sample_tokens(last, keys, temperature)
                 if not health_on:
                     return first, keys, pool, jnp.zeros(g, bool)
-                bad = ~jnp.all(jnp.isfinite(last), axis=-1)
+                bad = H.nonfinite_rows(last)
                 bad = bad | H.slot_scale_poison(pool, bt, lens, cfg)
                 return first, keys, pool, bad
 
@@ -656,7 +663,17 @@ class ContinuousBatchingEngine:
         starved slot out and retries next step; a kernel launch failure
         degrades that op to its dense path (``kernels.backend``) and the
         closures re-trace.  ``quarantined_in_step`` holds this cycle's
-        quarantined requests for the front end's retry budget."""
+        quarantined requests for the front end's retry budget.
+
+        The cycle's host phases are ``jax.profiler.TraceAnnotation``s on
+        the profiler's clock (``engine.step`` around ``engine.admit``,
+        ``engine.prefill``, ``engine.decode_window`` and
+        ``engine.fanout``); they cost nothing measurable while no
+        profiler runs."""
+        with TraceAnnotation("engine.step"):
+            return self._step()
+
+    def _step(self) -> List[Tuple[int, int]]:
         emitted: List[Tuple[int, int]] = []
         self.quarantined_in_step = []
         self._step_progress = False
@@ -664,18 +681,19 @@ class ContinuousBatchingEngine:
             self._consult_step_faults()
             if self.stall_aborted:
                 return emitted          # watchdog cut the stall short
-        if self.preempt:
-            # swap out one victim at a time until the waiting head fits
-            # (or no strictly lower-priority runner remains); the freed
-            # slots/pages are re-granted by the admit() right below
-            while True:
-                victim = self.scheduler.pick_victim()
-                if victim is None:
-                    break
-                self._swap_out(victim)
-        t0 = time.perf_counter()
-        admitted = self.scheduler.admit()
-        t_adm = time.perf_counter()
+        with TraceAnnotation("engine.admit"):
+            if self.preempt:
+                # swap out one victim at a time until the waiting head
+                # fits (or no strictly lower-priority runner remains); the
+                # freed slots/pages are re-granted by the admit() below
+                while True:
+                    victim = self.scheduler.pick_victim()
+                    if victim is None:
+                        break
+                    self._swap_out(victim)
+            t0 = time.perf_counter()
+            admitted = self.scheduler.admit()
+            t_adm = time.perf_counter()
         self._phase_add("sync", t_adm - t0)
         if self.tracer is not None:
             for r in admitted:
@@ -710,22 +728,41 @@ class ContinuousBatchingEngine:
         self._note_page_stats()             # post-grant working set
         snapshot = sorted(self.scheduler.running.items())
         rem0 = {slot: req.remaining for slot, req in snapshot}
-        bt = self._device_tables()
-        t1 = time.perf_counter()
-        toks, self.pool, _, _, self._slot_keys, badl, poison = \
-            self._multi(
-                self.params, jnp.asarray(self._cur_tok), self.pool, bt,
-                jnp.asarray(self._lengths), jnp.asarray(self._remaining),
-                self._slot_keys, window)
-        toks = np.asarray(toks)         # the one host transfer per window
-        if self.health_checks:
-            badl = np.asarray(badl)
-            poison = np.asarray(poison)
-            bad = badl | poison
-        else:
-            bad = np.zeros(toks.shape[1], bool)
-        t2 = time.perf_counter()
+        # the block-table upload feeds the window, so it opens the span;
+        # the span closes once the window's tokens are on the host, so
+        # every device op of the window lies inside it
+        with TraceAnnotation("engine.decode_window", steps=int(window),
+                             live=len(snapshot)):
+            bt = self._device_tables()
+            t1 = time.perf_counter()
+            toks, self.pool, _, _, self._slot_keys, badl, poison = \
+                self._multi(
+                    self.params, jnp.asarray(self._cur_tok), self.pool, bt,
+                    jnp.asarray(self._lengths),
+                    jnp.asarray(self._remaining), self._slot_keys, window)
+            toks = np.asarray(toks)     # the one host transfer per window
+            if self.health_checks:
+                badl = np.asarray(badl)
+                poison = np.asarray(poison)
+                bad = badl | poison
+            else:
+                bad = np.zeros(toks.shape[1], bool)
+            t2 = time.perf_counter()
+        with TraceAnnotation("engine.fanout"):
+            self._fanout(window, snapshot, rem0, toks, bad, badl, t1, t2,
+                         emitted)
+        self._phase_add("decode", t2 - t1)
+        self._phase_add("sync", (t1 - t0) + (time.perf_counter() - t2))
+        return emitted
+
+    def _fanout(self, window: int, snapshot, rem0, toks, bad, badl,
+                t1: float, t2: float, emitted) -> None:
+        """After a decode window: count it, hand each live slot its
+        tokens, then quarantine flagged slots and release finished
+        ones."""
         self._c_steps.inc(window)
+        self._c_live_steps.inc(sum(min(window, rem0[slot])
+                                   for slot, _ in snapshot))
         self._c_syncs.inc()
         self._h_window.observe(window)
         if self.tracer is not None:
@@ -766,9 +803,6 @@ class ContinuousBatchingEngine:
                 self._cur_tok[slot] = toks[take - 1, slot]
             if req.done:
                 self._release(req)
-        self._phase_add("decode", t2 - t1)
-        self._phase_add("sync", (t1 - t0) + (time.perf_counter() - t2))
-        return emitted
 
     def _consult_step_faults(self) -> None:
         """Step-scoped fault-injection sites (no-op without a plan):
@@ -975,11 +1009,12 @@ class ContinuousBatchingEngine:
             npr = lp // self.page_size
             page_ids = self.blocks.tables[slots, :npr]
             tb = time.perf_counter()
-            first, keys, self.pool, bad = self._prefill_scatter(
-                self.params, jnp.asarray(toks), jnp.asarray(lens),
-                fresh, self.pool, jnp.asarray(page_ids))
-            self._finish_prefill(reqs, slots, keys, first, emitted, bad,
-                                 t0=tb)
+            with TraceAnnotation("engine.prefill", group=g, bucket=lp):
+                first, keys, self.pool, bad = self._prefill_scatter(
+                    self.params, jnp.asarray(toks), jnp.asarray(lens),
+                    fresh, self.pool, jnp.asarray(page_ids))
+                self._finish_prefill(reqs, slots, keys, first, emitted,
+                                     bad, t0=tb)
         if hits:
             self._cow_forks(hits)
             self._hit_prefill(hits, emitted)
@@ -1034,12 +1069,13 @@ class ContinuousBatchingEngine:
             fresh = jax.vmap(lambda r: jax.random.fold_in(self._key, r))(
                 jnp.asarray([r.rid for r in reqs], jnp.uint32))
             tb = time.perf_counter()
-            first, keys, self.pool, bad = self._suffix_prefill(
-                self.params, jnp.asarray(toks), jnp.asarray(starts),
-                jnp.asarray(lens), fresh, self.pool,
-                bt[jnp.asarray(slots)])
-            self._finish_prefill(reqs, slots, keys, first, emitted, bad,
-                                 t0=tb)
+            with TraceAnnotation("engine.prefill", group=g, bucket=ls):
+                first, keys, self.pool, bad = self._suffix_prefill(
+                    self.params, jnp.asarray(toks), jnp.asarray(starts),
+                    jnp.asarray(lens), fresh, self.pool,
+                    bt[jnp.asarray(slots)])
+                self._finish_prefill(reqs, slots, keys, first, emitted,
+                                     bad, t0=tb)
 
     # ------------------------------------------------- preempt-and-swap
     def _swap_out(self, req: Request) -> None:

@@ -319,18 +319,21 @@ class AsyncServer:
 
     async def submit(self, prompt, max_new_tokens: int, *,
                      priority: int = 0,
-                     deadline_s: Optional[float] = None) -> RequestStream:
+                     deadline_s: Optional[float] = None,
+                     arrival_t: Optional[float] = None) -> RequestStream:
         """Accept one request; resolves to its :class:`RequestStream` once
         the step loop has applied the submission (or raises
         :class:`RejectedError` under ``admission="reject"``).
 
-        The arrival timestamp is taken *here* — queueing delay (backlog
+        The arrival timestamp is taken *here* unless the caller passes
+        ``arrival_t`` (a ``time.perf_counter`` stamp, e.g. when the
+        request was due under timed traffic) — queueing delay (backlog
         under ``"block"``, scheduler wait, preemption) all counts against
-        the request's TTFT.
+        the request's TTFT and its ``scheduler.queue_wait_s``.
         """
         if self._task is None:
             raise RuntimeError("server is not running")
-        arrival = time.perf_counter()
+        arrival = time.perf_counter() if arrival_t is None else arrival_t
         if self.admission == "block":
             async with self._space:
                 await self._space.wait_for(

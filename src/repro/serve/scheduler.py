@@ -161,6 +161,9 @@ class Scheduler:
             "sched.preemptions", "preempt() calls")
         self._c_restores = self.metrics.counter(
             "sched.restores", "SWAPPED re-admissions")
+        self._h_queue_wait = self.metrics.histogram(
+            "scheduler.queue_wait_s",
+            "first admission - arrival_t, seconds, observed at admission")
 
     # registry-backed counter views (setters: snapshot restore rewinds)
     @property
@@ -279,6 +282,9 @@ class Scheduler:
             req.state = RequestState.RUNNING
             if req.t_admitted is None:
                 req.t_admitted = time.perf_counter()
+                if req.arrival_t is not None:
+                    self._h_queue_wait.observe(
+                        req.t_admitted - req.arrival_t)
             self.running[slot] = req
             self.waiting.pop(0)
             admitted.append(req)

@@ -372,6 +372,37 @@ def test_engine_counters_equal_registry_snapshot(served):
     assert g["mx.poison_markers"]["role=kv_key"] == 0
 
 
+def test_live_slot_steps_and_queue_wait_in_the_snapshot(served):
+    """``engine.live_slot_steps`` counts one slot-step per token a decode
+    window hands a live request; ``scheduler.queue_wait_s`` takes each
+    admission's wait from the ``arrival_t`` the front end forwarded."""
+    import time
+    cfg, model, params = served
+    eng = _engine(model, params, max_slots=2)
+    prompts = _prompts(cfg)
+
+    async def go():
+        async with AsyncServer(eng) as srv:
+            due = time.perf_counter() - 0.25
+            streams = [await srv.submit(p, NEW, arrival_t=due)
+                       for p in prompts]
+            for st in streams:
+                await st.tokens()
+            return srv.obs_snapshot(), [st.request for st in streams], due
+
+    snap, reqs, due = asyncio.run(asyncio.wait_for(go(), TIMEOUT))
+    assert all(r.arrival_t == due for r in reqs)
+    c, h = snap["engine"]["counters"], snap["engine"]["histograms"]
+    # every token but each request's first (the prefill's) came from a
+    # live slot-step of a decode window
+    assert c["engine.live_slot_steps"] == 3 * NEW - 3
+    waits = h["scheduler.queue_wait_s"]
+    assert waits["count"] == 3
+    assert waits["min"] >= 0.25
+    assert waits["sum"] == pytest.approx(
+        sum(r.t_admitted - due for r in reqs))
+
+
 def test_engine_reset_metrics_zeroes_registry(served):
     cfg, model, params = served
     eng = _engine(model, params)
