@@ -19,8 +19,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.convert import (MXArray, mx_dequantize, mx_quantize,
-                                quantize_dequantize)
+from repro.core import formats as F
+from repro.core.convert import (MXArray, decode_elements, mx_dequantize,
+                                mx_quantize, pow2_f32, quantize_dequantize)
 from repro.core.mx_weight import MXWeight
 from repro.core.pack import pack_codes, unpack_codes
 from repro.core.spec import QuantPolicy, QuantSpec
@@ -413,8 +414,13 @@ def paged_cache_write_tokens(pool, k: jax.Array, v: jax.Array,
 def paged_cache_gather(pool, block_tables: jax.Array, cfg: ModelConfig,
                        dtype, hd: int) -> Tuple[jax.Array, jax.Array]:
     """Gather a slot-major contiguous (B, max_pages*page, n_kv, hd) K/V view
-    through the block table (dense-attention fallback path; the Pallas
-    kernel gathers at the HBM->VMEM boundary instead)."""
+    through the block table, dequantized to ``dtype``.
+
+    Serves the paged suffix prefill (``attention_paged_prefill``) and bf16
+    pools.  The prefill keeps it because it attends exactly the values the
+    contiguous prefill attends (``cache_read`` + ``_sdpa_gqa``), which is
+    what makes a shared-prefix suffix prefill bit-identical to the full
+    one.  The MX decode read is ``_paged_mx_read`` + ``_sdpa_gqa_mx``."""
     b, np_max = block_tables.shape
     if cfg.mx.kv_key is not None:
         def one(codes_key, scales_key, spec):
@@ -434,6 +440,112 @@ def paged_cache_gather(pool, block_tables: jax.Array, cfg: ModelConfig,
     k = k.reshape((b, -1) + k.shape[3:])
     v = v.reshape((b, -1) + v.shape[3:])
     return k.astype(dtype), v.astype(dtype)
+
+
+def _gather_pages_head_major(leaf: jax.Array,
+                             block_tables: jax.Array) -> jax.Array:
+    """Gather one pool leaf (P, page, n_kv, X) through the block table into
+    (B, n_kv, max_pages*page, X).  Pages and heads are indexed together, so
+    each head's context comes out as one run: the layout the per-head
+    contractions read, with no relayout of the gathered view."""
+    b = block_tables.shape[0]
+    nkv = leaf.shape[2]
+    out = jnp.swapaxes(leaf, 1, 2)[block_tables[:, None, :],
+                                   jnp.arange(nkv)[None, :, None]]
+    return out.reshape(b, nkv, -1, leaf.shape[-1])
+
+
+@scopes.scoped("kv_read")
+def _paged_mx_read(pool, codes_key: str, scales_key: str, spec: QuantSpec,
+                   block_tables: jax.Array, hd: int, factor_dtype
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """One side (K or V) of the dense MX paged decode read, kept factored.
+
+    Returns the gathered element values ``elem`` (B, n_kv, S, CL) in bf16
+    and the per-block factors ``factor`` (B, n_kv, CL // block, S), with
+    ``elem * factor`` (each factor over its block's lanes) equal to
+    ``mx_dequantize``'s values.  Every MX element value is exact in bf16
+    (INT8 as its integer, its 2^-6 moved into the factor), and a factor is
+    a power of two, so the product is what ``mx_dequantize(...).astype(
+    bf16)`` gives.  A scale byte at the mode's poison threshold
+    (``SCALE_NAN``; paper ``SCALE_INF`` too) makes its factor NaN, which
+    poisons the slot's output as the dequantized NaN/Inf block does.  The
+    factors are gathered as one lane-dense row per page and laid out with
+    positions along the lanes, as the score and weight contractions use
+    them; no dequantized context is ever written."""
+    fmt = spec.format
+    codes = pool[codes_key]
+    if fmt.is_int and spec.mode == "ocp":
+        # two's complement: reinterpret before the gather, so what is left
+        # of the decode is the s8 -> bf16 convert the contraction fuses
+        codes = jax.lax.bitcast_convert_type(codes, jnp.int8)
+    c = _gather_pages_head_major(codes, block_tables)
+    if spec.packed:
+        c = unpack_codes(c, spec.fmt, _code_len(hd, spec.block))
+    if fmt.is_int:
+        if spec.mode == "paper":                 # sign-magnitude 1.6
+            ci = c.astype(jnp.int32)
+            c = jnp.where((ci >> 7) == 1, -(ci & 0x7F), ci & 0x7F)
+        elem, shift = c.astype(jnp.bfloat16), -fmt.int_frac_bits
+    else:
+        elem, shift = decode_elements(c, fmt, spec.mode).astype(
+            jnp.bfloat16), 0
+    s = pool[scales_key]                         # (P, page, n_kv, nb)
+    n_pool, page, nkv, nb = s.shape
+    b, np_max = block_tables.shape
+    rows = s.reshape(n_pool, page * nkv * nb)[block_tables]
+    factor = jnp.where(rows >= F.poison_threshold(spec.mode), jnp.nan,
+                       pow2_f32(rows.astype(jnp.int32)
+                                - F.SCALE_BIAS + shift))
+    factor = factor.astype(factor_dtype).reshape(b, np_max, page, nkv, nb)
+    factor = jnp.transpose(factor, (0, 3, 4, 1, 2)).reshape(
+        b, nkv, nb, np_max * page)
+    return (logical(elem, "kv_batch", None, None, None),
+            logical(factor, "kv_batch", None, None, None))
+
+
+def _block_lanes(nb: int, width: int) -> jax.Array:
+    """(nb, width) bool: lane d belongs to scale block j."""
+    return (jnp.arange(width)[None, :] // (width // nb)
+            == jnp.arange(nb)[:, None])
+
+
+@scopes.scoped("attn_core")
+def _sdpa_gqa_mx(q, k_elem, k_factor, v_elem, v_factor,
+                 lengths) -> jax.Array:
+    """One-token GQA attention over factored MX K/V (``_paged_mx_read``).
+
+    q (B, 1, Hq, D); elements (B, Hkv, S, CL); factors (B, Hkv, nb, S).
+    The block factors are folded into the contractions instead of into
+    the context: the score contraction runs once per K scale block (the
+    query masked to that block's lanes), and each block's partial score is
+    scaled by its key's factor and summed; the bf16 softmax weights are
+    scaled by each value block's factor before the weighted sum, and each
+    output lane keeps its own block's sum.  Factors are powers of two, so
+    bf16(p) * f == bf16(p * f) and both sums carry the products of
+    ``_sdpa_gqa`` over dequantized K/V, summed in another order.  Slot b
+    attends positions <= lengths[b]; scores and softmax are f32."""
+    b, _, hq, hd = q.shape
+    hkv, sk, cl = k_elem.shape[1:]
+    rep = hq // hkv
+    qg = q.reshape(b, hkv, rep, hd)
+    if cl > hd:                                  # codes padded to a block
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, cl - hd)))
+    kb = _block_lanes(k_factor.shape[2], cl)
+    qb = qg[:, :, :, None, :] * kb.astype(q.dtype)        # (B, G, R, nb, CL)
+    part = jnp.einsum("bgrjd,bgkd->bgrjk", qb, k_elem,
+                      preferred_element_type=jnp.float32)
+    scores = jnp.einsum("bgrjk,bgjk->bgrk", part, k_factor) \
+        * (1.0 / np.sqrt(hd))
+    mask = jnp.arange(sk)[None, None, None, :] \
+        <= lengths[:, None, None, None]
+    probs = softmax_f32(jnp.where(mask, scores, -1e30)).astype(q.dtype)
+    weights = probs[:, :, :, None, :] * v_factor[:, :, None]
+    out = jnp.einsum("bgrjk,bgkd->bgrjd", weights, v_elem,
+                     preferred_element_type=jnp.float32)
+    vb = _block_lanes(v_factor.shape[2], v_elem.shape[-1])
+    out = jnp.sum(jnp.where(vb, out, 0.0), axis=3)[..., :hd]
+    return out.astype(q.dtype).reshape(b, 1, hq, hd)
 
 
 def attention_paged_decode(p: Params, x: jax.Array, cfg: ModelConfig, *,
@@ -467,11 +579,19 @@ def attention_paged_decode(p: Params, x: jax.Array, cfg: ModelConfig, *,
         from repro.kernels import backend
         from repro.kernels.ops import mx_paged_decode_attention_ctx
         # supervised dispatch: a degraded kernel (injected fault) returns
-        # None and the dense gather path below serves token-identically
+        # None and the dense read below serves token-identically
         with scopes.scope("attn_core"):
             out = backend.supervised(
                 "paged_attn", mx_paged_decode_attention_ctx, q, pool,
                 block_tables, lengths, cfg)
+    if out is None and cfg.mx.kv_key is not None:
+        # dense MX read: gathered codes and block factors, dequantized
+        # inside the contractions (slot-sharded, as the gathered view was)
+        ke, kf = _paged_mx_read(pool, "kc_pages", "ks_pages", cfg.mx.kv_key,
+                                block_tables, hd, jnp.float32)
+        ve, vf = _paged_mx_read(pool, "vc_pages", "vs_pages",
+                                cfg.mx.kv_value, block_tables, hd, x.dtype)
+        out = _sdpa_gqa_mx(q, ke, kf, ve, vf, lengths)
     if out is None:
         ka, va = paged_cache_gather(pool, block_tables, cfg, x.dtype, hd)
         # keep the gathered view slot-sharded (decode reads stay local);

@@ -1,5 +1,6 @@
 """The main-path Pallas kernels compile for a TPU v5e at chatglm3-6b's real
-widths.
+widths, and the dense paged decode read compiles without a dequantized copy
+of the context.
 
 Nothing runs: each kernel is lowered and compiled for a chip described by
 ``jax.experimental.topologies`` (the TPU compiler is installed without a
@@ -10,17 +11,21 @@ tests cannot.  The topology is described inside a fixture, never while a
 module is imported: only one process may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.spec import QuantSpec
+from repro.core.spec import QuantPolicy, QuantSpec
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.mx_decode_attn import mx_paged_decode_attention
 from repro.kernels.mx_matmul import mx_matmul_2d
 from repro.kernels.mx_quant import mx_quantize_2d
+from repro.models import layers
+from repro.models.config import ModelConfig
 
 # chatglm3-6b serving widths
 HQ, HKV, D, PAGE = 32, 2, 128, 16
@@ -104,3 +109,60 @@ def test_flash_attention_compiles(one_chip):
     q = _sds(one_chip, (1, 512, HQ, D), jnp.bfloat16)
     kv = _sds(one_chip, (1, 512, HKV, D), jnp.bfloat16)
     _compile(lambda q, k, v: flash_attention(q, k, v, True, False), q, kv, kv)
+
+
+def test_dense_paged_decode_read_keeps_the_context_quantized(one_chip):
+    """One layer's ``attention_paged_decode`` at the benchmark cell's
+    shapes (32 slots x 256 pages of 16, a 1280-page int8 pool, dense):
+    no f32 or bf16 tensor of the context's size anywhere in the compiled
+    program, no context-sized relayout copy, and fewer temporaries than
+    the dequantize-then-attend read (``paged_cache_gather`` +
+    ``_sdpa_gqa``) at the same shapes."""
+    slots, max_pages, n_pages = 32, 256, 1280
+    spec = QuantSpec("int8", "ocp")
+    cfg = ModelConfig(name="chatglm3-6b", family="decoder", n_layers=1,
+                      d_model=D_MODEL, n_heads=HQ, n_kv_heads=HKV,
+                      d_ff=D_FF, vocab=65024, head_dim=D, rope_frac=0.5,
+                      mx=QuantPolicy(kv_key=spec, kv_value=spec),
+                      attn_impl="dense")
+    s = lambda shape, dt=jnp.uint8: _sds(one_chip, shape, dt)
+    pool = {"kc_pages": s((n_pages, PAGE, HKV, D)),
+            "ks_pages": s((n_pages, PAGE, HKV, D // 32)),
+            "vc_pages": s((n_pages, PAGE, HKV, D)),
+            "vs_pages": s((n_pages, PAGE, HKV, D // 32))}
+    w = lambda n_in, n_out: s((n_in, n_out), jnp.bfloat16)
+    p = {"wq": w(D_MODEL, HQ * D), "wk": w(D_MODEL, HKV * D),
+         "wv": w(D_MODEL, HKV * D), "wo": w(HQ * D, D_MODEL)}
+    bt, lengths = s((slots, max_pages), jnp.int32), s((slots,), jnp.int32)
+
+    def layer(p, x, pool, bt, lengths):
+        return layers.attention_paged_decode(p, x, cfg, pool=pool,
+                                             block_tables=bt,
+                                             lengths=lengths)
+
+    def dequantized_read(q, pool, bt, lengths):
+        ka, va = layers.paged_cache_gather(pool, bt, cfg, jnp.bfloat16, D)
+        mask = jnp.arange(ka.shape[1])[None, None, None, None, :] \
+            <= lengths[:, None, None, None, None]
+        return layers._sdpa_gqa(q, ka, va, mask)
+
+    new = jax.jit(layer).lower(p, s((slots, 1, D_MODEL), jnp.bfloat16),
+                               pool, bt, lengths).compile()
+    old = jax.jit(dequantized_read).lower(
+        s((slots, 1, HQ, D), jnp.bfloat16), pool, bt, lengths).compile()
+    context = slots * max_pages * PAGE * HKV * D
+    text = new.as_text()
+    sized = [m.group(0) for m in re.finditer(
+        r"\b(f32|bf16|s8|u8)\[([\d,]+)\]", text)
+        if np.prod([int(n) for n in m.group(2).split(",")]) == context]
+    assert sized, "the gathered codes should be context-sized"
+    assert not [t for t in sized if t.startswith(("f32", "bf16"))], \
+        sorted(set(sized))
+    copies = [line for line in text.splitlines()
+              if re.search(r"= \w+\[[\d,]+\]\S* copy\(", line) and np.prod(
+                  [int(n) for n in re.search(r"\[([\d,]+)\]",
+                                             line).group(1).split(",")])
+              == context]
+    assert not copies, copies
+    assert new.memory_analysis().temp_size_in_bytes \
+        < old.memory_analysis().temp_size_in_bytes
