@@ -11,13 +11,19 @@ A mix is a JSON file of parameters (``bench/traffic/<name>.json``):
                 "max": hi}``.
 ``set_size``    the number of distinct (prompt, output) sizes and
                 inter-arrival gaps.
+``pairing``     ``seed`` (the default): each pass pairs prompt and output
+                sizes afresh; ``fixed``: the pairs are drawn once for the
+                mix, the same under every seed.
 
 Every seed gets the same multiset of sizes and gaps, in its own order: the
 sizes are the distribution's quantiles at (i + 1/2) / set_size and the gaps
-the exponential's, and the seed shuffles them.  So two seeds offer the same
-work in the window and differ only in its order and token ids, which keeps
-run-to-run spread down without narrowing the mix.  The sequence repeats
-the set, each pass in a fresh order.
+the exponential's, scaled so that their mean is 1 / rate_per_s, and the
+seed shuffles them.  The sequence repeats the set, each pass in a fresh
+order.  An open-loop schedule is laid so that a pass begins half the
+shortest gap before ``ramp_s``, where the window opens: a window of
+set_size / rate_per_s seconds, or a whole number of them, then holds whole
+passes, and every seed offers it the same requests (with ``fixed``
+pairing) in another order and with other token ids.
 """
 from __future__ import annotations
 
@@ -54,7 +60,8 @@ def _gaps(arr: Dict, n: int) -> np.ndarray:
     if rate <= 0:
         raise ValueError(f"rate_per_s must be > 0, got {rate}")
     p = (np.arange(n) + 0.5) / n
-    return -np.log1p(-p) / rate
+    g = -np.log1p(-p)
+    return g / (g.mean() * rate)
 
 
 class Traffic:
@@ -71,20 +78,33 @@ class Traffic:
         self._rng = np.random.default_rng(seed)
         self._plen = _quantiles(mix["prompt_len"], self.n_set)
         self._olen = _quantiles(mix["output_len"], self.n_set)
-        self._gaps = _gaps(mix["arrivals"], self.n_set) \
-            if self.kind == "open_loop" else None
+        pairing = mix.get("pairing", "seed")
+        if pairing not in ("seed", "fixed"):
+            raise ValueError(f"unknown pairing {pairing!r}")
+        self._pairs = np.random.default_rng(0).permutation(self.n_set) \
+            if pairing == "fixed" else None
         self.reqs: List[Req] = []
+        self._gaps = None
         self._t = 0.0                   # due time of the last request
+        if self.kind == "open_loop":
+            self._gaps = _gaps(mix["arrivals"], self.n_set)
+            pass_s = float(self._gaps.sum())
+            start = float(mix["ramp_s"]) - 0.5 * float(self._gaps.min())
+            self._t = start - np.ceil(start / pass_s) * pass_s
 
     def _extend(self) -> None:
-        """Append one pass over the size set in a fresh order."""
+        """Append one pass over the size set in a fresh order (of an
+        open-loop schedule's first pass, the requests due from 0 on)."""
         order_p = self._rng.permutation(self.n_set)
-        order_o = self._rng.permutation(self.n_set)
+        order_o = self._rng.permutation(self.n_set) \
+            if self._pairs is None else self._pairs[order_p]
         gaps = None if self._gaps is None \
             else self._gaps[self._rng.permutation(self.n_set)]
         for j in range(self.n_set):
             if gaps is not None:
                 self._t += float(gaps[j])
+                if self._t < 0:
+                    continue
             n = int(self._plen[order_p[j]])
             prompt = self._rng.integers(0, self.vocab, n, dtype=np.int32)
             self.reqs.append(Req(len(self.reqs), self._t, prompt,

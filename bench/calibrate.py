@@ -23,13 +23,13 @@ import json
 import sys
 import time
 
+import jax
 import numpy as np
 
 from run import find_devices, log, served_ok, use_cache, verdict
 
-from bench import cells, reference, serving
+from bench import cells, serving
 from bench.arrivals import Traffic
-from bench.weights import make_params
 
 
 def main() -> int:
@@ -42,29 +42,37 @@ def main() -> int:
     args = ap.parse_args()
     cell = cells.load_cell(args.workload)
     use_cache()
-    find_devices(cell.chips)
     config, mix = cell.config, cell.traffic
     arch = config["arch"]
+    where = serving.placement(config, find_devices(cell.chips))
+    weights = cells.bench_module(config, "weights")
+    reference = cells.bench_module(config, "reference")
     seeds = [int(s) for s in args.seeds.split(",")]
-    model, params = serving.build(config, seeds[0])
-    engine = serving.engine_for(model, params, config)
-    serving.warm(engine, config, mix, arch["vocab"])
+    with where.scope():
+        model, params = serving.build(config, seeds[0], where)
+        engine = serving.engine_for(model, params, config, where)
+        serving.warm(engine, config, mix, arch["vocab"])
+    shardings = jax.tree_util.tree_map(lambda a: a.sharding, params) \
+        if where.mesh is not None else None
     rec = serving.Recorder(engine, annotate=False)
-    sess = serving.Session(engine, rec, config["engine"]["max_queued"])
+    sess = serving.Session(engine, rec, config["engine"]["max_queued"],
+                           where)
     drive = serving.open_loop if mix["kind"] == "open_loop" \
         else serving.backlog
     rows = []
     for j, seed in enumerate(seeds):
         t0 = time.perf_counter()
-        if j:
-            engine.params = make_params(seed, arch)
-        rec.steps.clear()           # a backlog window waits for new steps
-        win = asyncio.run(drive(sess, mix, Traffic(mix, seed, arch["vocab"]),
-                                args.seconds))
-        sched = engine.scheduler
-        for r in list(sched.running.values()):
-            engine._release(r)      # drop what the halted window left
-        sched.waiting.clear()
+        with where.scope():
+            if j:
+                engine.params = weights.make_params(seed, arch, shardings)
+            rec.steps.clear()       # a backlog window waits for new steps
+            win = asyncio.run(drive(sess, mix,
+                                    Traffic(mix, seed, arch["vocab"]),
+                                    args.seconds))
+            sched = engine.scheduler
+            for r in list(sched.running.values()):
+                engine._release(r)  # drop what the halted window left
+            sched.waiting.clear()
         _, failed, served = served_ok(win, mix["kind"], arch["vocab"])
         engine.params = None
         del params

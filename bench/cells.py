@@ -2,8 +2,10 @@
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
+import math
 from pathlib import Path
 from typing import Any, Callable, Dict, List
 
@@ -60,6 +62,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    check_mesh(config, int(w["chips"]))
     traffic = json.loads(traffic_file(w["traffic"], root / "bench")
                          .read_text())
     traffic.setdefault("name", w["traffic"])
@@ -68,6 +71,25 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     layer = [Metric(m["name"], m["unit"], m["better"], m["source"], m)
              for m in spec["per_layer"] if _applies(m, name)]
     return Cell(name, int(w["chips"]), config, traffic, e2e, layer)
+
+
+def check_mesh(config: Dict[str, Any], chips: int) -> None:
+    """A configuration's ``mesh`` ({axis: size}) has to span exactly the
+    cell's chips.  A configuration without one runs on one device."""
+    mesh = config.get("mesh")
+    if mesh is None:
+        return
+    n = math.prod(int(v) for v in mesh.values())
+    if n != chips:
+        raise ValueError(f"configuration {config.get('name')!r}: mesh "
+                         f"{mesh} spans {n} devices, the cell asks for "
+                         f"{chips} chips")
+
+
+def bench_module(config: Dict[str, Any], role: str):
+    """The module a configuration names for ``role`` ("weights" or
+    "reference"): ``bench/<name>.py``, by default ``bench/<role>.py``."""
+    return importlib.import_module(f"bench.{config.get(role, role)}")
 
 
 def metric_reader(name: str, bench: Path = BENCH) -> Callable[[Any], Any]:

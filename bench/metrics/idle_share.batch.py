@@ -1,5 +1,5 @@
 """Device: share of the traced window in which no operation ran on the
-chip (%)."""
+first chip, the only one a one-chip cell has (%)."""
 from bench import tracefile
 
 

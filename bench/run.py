@@ -9,11 +9,13 @@ device, warms every shape the cell's traffic uses and brings the traffic
 to steady state; then the window runs for ``--seconds``.  With ``--trace
 0`` the result carries the cell's end-to-end metrics; with ``--trace 1``
 the window runs under the profiler and the result carries its per-layer
-metrics, the device's busy time and a breakdown.  Afterwards a sample of
-the served requests, drawn from the seed, is compared with the float32
-reference (``bench/reference.py``); the last line of standard output is
-the JSON result.  Without a TPU, or with fewer chips than the cell asks
-for, the run exits non-zero and prints no result.
+metrics, the device's busy time and a breakdown.  A configuration with a
+``mesh`` runs over that many chips, its weights placed as the program
+places them.  Afterwards a sample of the served requests, drawn from the
+seed, is compared with the float32 reference the configuration names
+(``bench/reference.py`` unless it names another); the last line of
+standard output is the JSON result.  Without a TPU, or with fewer chips
+than the cell asks for, the run exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ for p in (ROOT, ROOT / "src"):
 
 import numpy as np  # noqa: E402
 
-from bench import cells, reference, serving, stats  # noqa: E402
+from bench import cells, serving, stats  # noqa: E402
 from bench.arrivals import Traffic  # noqa: E402
 from bench.flops import peaks  # noqa: E402
 
@@ -103,7 +105,8 @@ class RunRecord:
         self.trace_bounds = trace_bounds    # (t0_ns, t1_ns) of the window
 
     def device_ops(self):
-        """The first chip's ops in the traced window, or None."""
+        """The first chip's ops in the traced window, or None.  On a mesh
+        this is one chip of several: the others run the same program."""
         if self.trace is None or not self.trace.devices:
             return None
         return self.trace.devices[sorted(self.trace.devices)[0]]
@@ -150,20 +153,50 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
              mutate=None) -> dict:
     """One run of ``cell``; returns the result object.  ``mutate(session)``
     may break the system before the window (the harness's own tests)."""
-    import jax
     t_process = time.perf_counter() if t_process is None else t_process
     devs = find_devices(cell.chips, allow_cpu)
+    config = cell.config
+    where = serving.placement(config, devs)
+    with where.scope():
+        result, served = _serve(cell, devs, where, seed, seconds, trace,
+                                t_process, allow_cpu, mutate)
+    # the comparison runs once the program's state is freed, off the mesh
+    gc.collect()
+    check = config["check"]
+    reference = cells.bench_module(config, "reference")
+    rng = np.random.default_rng([int(seed), 7])
+    sample = reference.sample(served, int(check["sample"]), rng)
+    t_ref = time.perf_counter()
+    gaps = reference.served_gaps(seed, config["arch"], sample)
+    log(f"reference over {len(sample)} requests, {int(gaps['tokens'])} "
+        f"served tokens in {time.perf_counter() - t_ref:.3f}s; one last "
+        f"token moved to its neighbour would read max_logit_gap "
+        f"{gaps['altered_max_logit_gap']}")
+    checks, result["correct"] = verdict(gaps, result["failed"], len(sample),
+                                        check)
+    result["checks"] = checks
+    for name, c in checks.items():
+        log(f"check {name} {c['value']} limit {c['limit']}")
+    return result
+
+
+def _serve(cell, devs, where, seed, seconds, trace, t_process, allow_cpu,
+           mutate):
+    """Build, warm and drive the system on ``where``; (the result without
+    its comparison, the served (prompt, tokens) pairs)."""
+    import jax
     kind = devs[0].device_kind
     peak = peaks(kind) if not allow_cpu else peaks("TPU v5 lite")
     clock = serving.CompileClock(jax)
     config, mix = cell.config, cell.traffic
     arch = config["arch"]
 
-    model, params = serving.build(config, seed)
-    engine = serving.engine_for(model, params, config)
+    model, params = serving.build(config, seed, where)
+    engine = serving.engine_for(model, params, config, where)
     n_warm = serving.warm(engine, config, mix, arch["vocab"])
     rec = serving.Recorder(engine, annotate=trace)
-    sess = serving.Session(engine, rec, config["engine"]["max_queued"])
+    sess = serving.Session(engine, rec, config["engine"]["max_queued"],
+                           where)
     if mutate is not None:
         mutate(sess)
     traffic = Traffic(mix, seed, arch["vocab"])
@@ -188,8 +221,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                             min(seconds, TRACE_S) if trace else 0.0))
     setup_s = win.w0 - t_process
     compiles = marks["end"][1] - marks["start"][1]
-    dev = devs[0]
-    mem = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    mem = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+           for d in devs]
     log(f"window {win.w1 - win.w0:.3f}s: {len(win.judged)} judged, "
         f"{len(rec.steps)} steps, {compiles} compiles in the window; "
         f"setup {setup_s:.3f}s with {n_warm} warm-up requests and "
@@ -217,36 +250,20 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         e2e = end_to_end(cell, win, record, setup_s)
         result["metrics"] = {m.name: {"value": e2e[m.name], "unit": m.unit}
                              for m in cell.end_to_end if m.name in e2e}
-    result["device"] = {"platform": dev.platform, "kind": kind,
-                        "count": len(devs), "memory_peak_bytes": int(mem)}
-    if trace:
-        ops = record.device_ops()
-        if ops is not None and bounds is not None:
-            from bench import tracefile
-            t0, t1 = bounds
-            result["device"]["busy_s"] = tracefile.busy_ns(ops, t0, t1) / 1e9
-            result["device"]["window_s"] = (t1 - t0) / 1e9
-            result["breakdown"] = {
-                "device_ops": tracefile.top_ops(ops, t0, t1),
-                "idle_gaps": tracefile.idle_gaps(ops, tr.spans, t0, t1)}
-
-    # the comparison runs once the program's state is freed
-    check = config["check"]
-    del sess, rec, engine, params, model, record, tr
-    gc.collect()
-    rng = np.random.default_rng([int(seed), 7])
-    sample = reference.sample(served, int(check["sample"]), rng)
-    t_ref = time.perf_counter()
-    gaps = reference.served_gaps(seed, arch, sample)
-    log(f"reference over {len(sample)} requests, {int(gaps['tokens'])} "
-        f"served tokens in {time.perf_counter() - t_ref:.3f}s; one last "
-        f"token moved to its neighbour would read max_logit_gap "
-        f"{gaps['altered_max_logit_gap']}")
-    checks, result["correct"] = verdict(gaps, failed, len(sample), check)
-    result["checks"] = checks
-    for name, c in checks.items():
-        log(f"check {name} {c['value']} limit {c['limit']}")
-    return result
+    result["device"] = {"platform": devs[0].platform, "kind": kind,
+                        "count": len(devs), "memory_peak_bytes": max(mem),
+                        "memory_peak_bytes_per_device": mem}
+    ops = record.device_ops()
+    if ops is not None and bounds is not None:
+        from bench import tracefile
+        t0, t1 = bounds
+        busy = [tracefile.busy_ns(o, t0, t1) for o in tr.devices.values()]
+        result["device"]["busy_s"] = sum(busy) / len(busy) / 1e9
+        result["device"]["window_s"] = (t1 - t0) / 1e9
+        result["breakdown"] = {
+            "device_ops": tracefile.top_ops(ops, t0, t1),
+            "idle_gaps": tracefile.idle_gaps(ops, tr.spans, t0, t1)}
+    return result, served
 
 
 def verdict(gaps: dict, failed: int, compared: int, check: dict):

@@ -80,22 +80,67 @@ class ReqRec:
 
 
 def program_config(config: Dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: its
+    ``family`` (a decoder unless it says otherwise) and every key of its
+    ``arch``."""
     from repro.models.config import ModelConfig, QuantPolicy
     arch = config["arch"]
-    return ModelConfig(name=config["name"], family="decoder",
+    return ModelConfig(name=config["name"],
+                       family=config.get("family", "decoder"),
                        mx=QuantPolicy.parse(config["policy"]),
                        attn_impl=config["attn_impl"], **arch)
 
 
-def build(config: Dict, seed: int):
-    """(model, params) with weights from ``seed`` made on the device."""
+@dataclasses.dataclass
+class Placement:
+    """Where the cell's program runs: the default device, or the
+    configuration's ``mesh`` with the program's decode-posture rules
+    (weights resident and tensor-parallel)."""
+    mesh: Any = None
+    rules: Optional[Dict] = None
+
+    def scope(self):
+        """The mesh as JAX's context mesh (the program's sharding
+        constraints resolve against it), or nothing."""
+        import jax
+        return contextlib.nullcontext() if self.mesh is None \
+            else jax.set_mesh(self.mesh)
+
+
+def placement(config: Dict, devs) -> Placement:
+    """The configuration's ``mesh`` ({axis: size}, in mesh order) over
+    ``devs``, whose number it has to match; one device without it."""
+    shape = config.get("mesh")
+    if shape is None:
+        return Placement()
+    import jax
+    from jax.sharding import AxisType
+    from repro.dist.sharding import make_rules
+    from repro.models.config import QuantPolicy
+    axes = tuple(shape)
+    mesh = jax.make_mesh(tuple(int(shape[a]) for a in axes), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=list(devs))
+    rules = make_rules(axes, fsdp_params=False,
+                       quant=QuantPolicy.parse(config["policy"]))
+    return Placement(mesh, rules)
+
+
+def build(config: Dict, seed: int, where: Placement = Placement()):
+    """(model, params) with weights from ``seed`` made on the device, or
+    on ``where``'s mesh as the program places its parameters, by the
+    weights module the configuration names."""
     import jax
     from repro.models import Model
-    from bench.weights import make_params
+    from bench.cells import bench_module
     model = Model(program_config(config))
-    params = make_params(seed, config["arch"])
     expect = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    shardings = None
+    if where.mesh is not None:
+        from repro.launch.cells import shardings_for_params
+        shardings = shardings_for_params(expect, where.mesh)
+    params = bench_module(config, "weights").make_params(
+        seed, config["arch"], shardings)
     got = jax.eval_shape(lambda: params)
     if jax.tree_util.tree_structure(expect) != \
             jax.tree_util.tree_structure(got) or any(
@@ -108,13 +153,14 @@ def build(config: Dict, seed: int):
     return model, params
 
 
-def engine_for(model, params, config: Dict):
+def engine_for(model, params, config: Dict, where: Placement = Placement()):
     from repro.serve import ContinuousBatchingEngine
     e = config["engine"]
     return ContinuousBatchingEngine(
         model, params, max_slots=e["max_slots"], page_size=e["page_size"],
         max_len=e["max_len"], num_pages=e["num_pages"],
-        sync_every=e["sync_every"], prefill_bucket=e["prefill_bucket"])
+        sync_every=e["sync_every"], prefill_bucket=e["prefill_bucket"],
+        rules=where.rules)
 
 
 def prefill_shapes(config: Dict, mix: Dict) -> List[Tuple[int, int]]:
@@ -237,15 +283,20 @@ class Session:
     engine: Any
     recorder: Recorder
     max_queued: int
+    where: Placement = dataclasses.field(default_factory=Placement)
 
 
 class _Server:
     """An AsyncServer with a private one-thread executor, so that the
-    harness can stop it at a step boundary and wait for the step."""
+    harness can stop it at a step boundary and wait for the step.  JAX's
+    context mesh is per thread, so the executor's thread, which runs the
+    engine's steps, enters the session's mesh itself: the steps then find
+    the programs the warm-up compiled under it."""
 
     def __init__(self, sess: Session):
         from repro.serve.frontend import AsyncServer
-        self.pool = concurrent.futures.ThreadPoolExecutor(max_workers=1)
+        self.pool = concurrent.futures.ThreadPoolExecutor(
+            max_workers=1, initializer=sess.where.scope)
         self.server = AsyncServer(sess.engine, max_queued=sess.max_queued,
                                   use_executor=True)
 

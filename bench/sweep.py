@@ -33,13 +33,25 @@ def main() -> int:
     args = ap.parse_args()
     cell = cells.load_cell(args.workload)
     use_cache()
-    find_devices(cell.chips)
     config = cell.config
-    model, params = serving.build(config, args.seed)
-    engine = serving.engine_for(model, params, config)
+    where = serving.placement(config, find_devices(cell.chips))
+    with where.scope():
+        rows = _sweep(cell, where, args)
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(rows, f, indent=1)
+    print(json.dumps(rows))
+    return 0
+
+
+def _sweep(cell, where, args):
+    config = cell.config
+    model, params = serving.build(config, args.seed, where)
+    engine = serving.engine_for(model, params, config, where)
     serving.warm(engine, config, cell.traffic, config["arch"]["vocab"])
     rec = serving.Recorder(engine, annotate=False)
-    sess = serving.Session(engine, rec, config["engine"]["max_queued"])
+    sess = serving.Session(engine, rec, config["engine"]["max_queued"],
+                           where)
     rows = []
     for rate in [float(r) for r in args.rates.split(",")]:
         mix = dict(cell.traffic)
@@ -77,11 +89,7 @@ def main() -> int:
         row["wall_s"] = time.perf_counter() - t0
         log(json.dumps(row))
         rows.append(row)
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(rows, f, indent=1)
-    print(json.dumps(rows))
-    return 0
+    return rows
 
 
 if __name__ == "__main__":

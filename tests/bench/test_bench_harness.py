@@ -109,19 +109,46 @@ def test_mix_is_deterministic_under_the_seed(kind):
                       for r in rs]
     assert key(a) == key(b)
     assert key(a) != key(c)
-    # every seed offers the same sizes in each pass over the set
-    n = mix(kind)["set_size"]
-    sizes = lambda rs: sorted((len(r.prompt)) for r in rs[:n])
-    outs = lambda rs: sorted(r.max_new_tokens for r in rs[:n])
+    # every seed offers the same sizes in each pass over the set; an
+    # open-loop pass begins where the ramp ends
+    m = mix(kind)
+    n = m["set_size"]
+    pass_of = lambda rs: rs[:n] if kind == "backlog" else [
+        r for r in rs if m["ramp_s"] <= r.due_s
+        < m["ramp_s"] + n / m["arrivals"]["rate_per_s"]]
+    assert len(pass_of(a)) == len(pass_of(c)) == n
+    sizes = lambda rs: sorted(len(r.prompt) for r in pass_of(rs))
+    outs = lambda rs: sorted(r.max_new_tokens for r in pass_of(rs))
     assert sizes(a) == sizes(c) and outs(a) == outs(c)
     if kind == "open_loop":
-        gaps = lambda rs: sorted(np.round(np.diff([0.0] + [
-            r.due_s for r in rs[:n]]), 9))
+        def gaps(rs):              # the pass's gaps, its first one too
+            i = pass_of(rs)[0].index
+            return sorted(np.round(np.diff([r.due_s
+                                            for r in rs[i - 1:i + n]]), 9))
         assert gaps(a) == gaps(c)
+        assert np.isclose(sum(gaps(a)), n / m["arrivals"]["rate_per_s"])
+
+
+@pytest.mark.parametrize("pairing", ["seed", "fixed"])
+def test_fixed_pairing_gives_every_seed_the_same_requests(pairing):
+    """With ``pairing: fixed`` a window of one pass judges the same
+    (prompt, output) sizes under every seed; by default each pass pairs
+    them afresh."""
+    m = dict(mix(), pairing=pairing, set_size=32)
+    pass_s = 32 / m["arrivals"]["rate_per_s"]
+    pairs = []
+    for seed in (5, 2 ** 31 + 11, 77):
+        rs = Traffic(m, seed, 512).until(m["ramp_s"] + pass_s)
+        judged = [r for r in rs if m["ramp_s"] <= r.due_s]
+        assert len(judged) == 32
+        pairs.append(sorted((len(r.prompt), r.max_new_tokens)
+                            for r in judged))
+    same = pairs[0] == pairs[1] == pairs[2]
+    assert same == (pairing == "fixed")
 
 
 def test_lognormal_sizes_follow_the_mix():
-    m = dict(mix(), set_size=1001)
+    m = dict(mix("backlog"), set_size=1001)
     rs = Traffic(m, 3, 512).take(1001)
     lens = sorted(len(r.prompt) for r in rs)
     assert lens[0] >= 8 and lens[-1] <= 64
